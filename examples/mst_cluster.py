@@ -10,6 +10,7 @@ import time
 import jax
 import numpy as np
 
+from repro import platform
 from repro.core import generators, kruskal_ref
 from repro.core.mst_api import minimum_spanning_forest
 
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--kind", default="rmat")
     ap.add_argument("--method", default="boruvka", choices=["boruvka", "ghs"])
     args = ap.parse_args()
+    platform.use_compile_cache()
 
     mesh = None
     if args.shards > 1:

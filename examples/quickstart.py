@@ -4,12 +4,14 @@
 """
 import numpy as np
 
+from repro import platform
 from repro.core import generators, kruskal_ref
 from repro.core.mst_api import minimum_spanning_forest
 from repro.core.params import GHSParams
 
 
 def main():
+    platform.use_compile_cache()
     # An RMAT graph, paper-style: SCALE=10 (1024 vertices), avg degree 32.
     g = generators.generate("rmat", 10, seed=42)
     print(f"graph: {g.num_vertices} vertices, {g.num_edges} edges")

@@ -1,11 +1,9 @@
-"""Version-compat shims over the jax API surface the engines depend on.
+"""One spelling of the mesh and ``shard_map`` constructors.
 
-The repo targets the modern ``jax.shard_map`` / ``jax.sharding.AxisType``
-surface but must also run on older jax releases where ``shard_map`` still
-lives in ``jax.experimental`` (with ``check_rep`` instead of ``check_vma``)
-and meshes carry no axis types.  Every mesh/shard_map construction in the
-repo goes through this module so the engines, benchmarks, and subprocess
-tests agree on one spelling.
+Every mesh and ``shard_map`` in the repo (engines, benchmarks, subprocess
+tests) is built here, so replication checking and axis types are chosen in
+one place: ``jax.shard_map`` with ``check_vma=False`` and meshes whose axes
+are all ``AxisType.Auto``.
 """
 from __future__ import annotations
 
@@ -13,19 +11,13 @@ import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

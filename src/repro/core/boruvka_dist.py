@@ -41,7 +41,6 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
@@ -96,7 +95,7 @@ def _make_pmin(axis_name: Optional[str], collective: str,
     if axis_name is None:
         return lambda x, default=None: x
     if collective != "compressed" or cand_cap is None:
-        return lambda x, default=None: jax.lax.pmin(x, axis_name)
+        return lambda x, default=None: collectives.pmin(x, axis_name)
 
     def pmin(x, default):
         return collectives.pmin_compressed(
@@ -177,6 +176,38 @@ def _one_round(
     return parent[comp], mask, done
 
 
+def _censuses(comp, src, dst, key, axis_name: Optional[str],
+              collective: str):
+    """(max local active edges, max local candidate fragments) after an
+    interval.
+
+    The active-edge count picks the host's compaction bucket.  The
+    candidate census caps the compressed collective: distinct fragments
+    touched by local active edges bound every entry a shard can improve in
+    ANY later round (fragments only merge and edges only die, so the count
+    is non-increasing — valid even consumed one interval late under the
+    double-buffered driver).  It costs a sort over both endpoint lanes, so
+    it is taken only where a compressed exchange can read it (a mesh under
+    ``collective="compressed"``); elsewhere the trivial bound ``n`` stands.
+    """
+    active = (comp[src] != comp[dst]) & (key != INF_KEY)
+    n_active = active.sum(dtype=jnp.int32)
+    n = comp.shape[0]
+    if not axis_name:
+        return n_active, jnp.int32(n)
+    if collective == "compressed":
+        seg = jnp.concatenate([comp[src], comp[dst]]).astype(jnp.uint32)
+        seg = jnp.where(jnp.concatenate([active, active]), seg,
+                        jnp.uint32(n))
+        (seg,) = jax.lax.sort((seg,), num_keys=1)
+        first = (seg != jnp.uint32(n)) & jnp.concatenate(
+            [jnp.ones((1,), bool), seg[1:] != seg[:-1]])
+        n_cand = jax.lax.pmax(first.sum(dtype=jnp.int32), axis_name)
+    else:
+        n_cand = jnp.int32(n)
+    return jax.lax.pmax(n_active, axis_name), n_cand
+
+
 def _run_interval(
     comp: jnp.ndarray,
     mask: jnp.ndarray,
@@ -225,26 +256,8 @@ def _run_interval(
 
     r, comp, mask, done = jax.lax.while_loop(
         cond, body, (jnp.int32(0), comp, mask, jnp.bool_(False)))
-
-    # Active-edge census for the host's compaction-bucket choice, plus the
-    # candidate census for the compressed-collective cap: distinct
-    # fragments touched by local active edges bound every entry a shard
-    # can improve in ANY later round (fragments only merge and edges only
-    # die, so the count is non-increasing — valid even consumed one
-    # interval late under the double-buffered driver).
-    active = (comp[src] != comp[dst]) & (key != INF_KEY)
-    n_active = active.sum(dtype=jnp.int32)
-    n = comp.shape[0]
-    seg = jnp.concatenate([comp[src], comp[dst]]).astype(jnp.uint32)
-    seg = jnp.where(jnp.concatenate([active, active]), seg, jnp.uint32(n))
-    (seg,) = jax.lax.sort((seg,), num_keys=1)
-    first = (seg != jnp.uint32(n)) & jnp.concatenate(
-        [jnp.ones((1,), bool), seg[1:] != seg[:-1]])
-    n_cand = first.sum(dtype=jnp.int32)
-    if axis_name:
-        n_active = jax.lax.pmax(n_active, axis_name)
-        n_cand = jax.lax.pmax(n_cand, axis_name)
-    return comp, mask, done, r, n_active, n_cand
+    return (comp, mask, done, r,
+            *_censuses(comp, src, dst, key, axis_name, collective))
 
 
 def _one_round_fused(
@@ -307,8 +320,7 @@ def _one_round_fused(
     hi = jnp.maximum(f, other)
     lo = jnp.minimum(f, other)
     parent = union_find.hook_min(n, hi, lo, elected)
-    comp = spmv_ops.shortcut_relabel(parent, comp,
-                                     use_pallas=(lowering == "pallas"))
+    comp = spmv_ops.shortcut_relabel(parent, comp)
     done = jnp.all(best == INF_KEY)
     return comp, mask, done
 
@@ -359,22 +371,8 @@ def _run_interval_fused(
 
     r, comp, mask, done = jax.lax.while_loop(
         cond, body, (jnp.int32(0), comp, mask, jnp.bool_(False)))
-
-    # Same censuses as _run_interval (active for compaction, distinct
-    # touched fragments for the compressed-collective cap).
-    active = (comp[src] != comp[dst]) & (key != INF_KEY)
-    n_active = active.sum(dtype=jnp.int32)
-    n = comp.shape[0]
-    seg = jnp.concatenate([comp[src], comp[dst]]).astype(jnp.uint32)
-    seg = jnp.where(jnp.concatenate([active, active]), seg, jnp.uint32(n))
-    (seg,) = jax.lax.sort((seg,), num_keys=1)
-    first = (seg != jnp.uint32(n)) & jnp.concatenate(
-        [jnp.ones((1,), bool), seg[1:] != seg[:-1]])
-    n_cand = first.sum(dtype=jnp.int32)
-    if axis_name:
-        n_active = jax.lax.pmax(n_active, axis_name)
-        n_cand = jax.lax.pmax(n_cand, axis_name)
-    return comp, mask, done, r, n_active, n_cand
+    return (comp, mask, done, r,
+            *_censuses(comp, src, dst, key, axis_name, collective))
 
 
 @functools.lru_cache(maxsize=64)
@@ -385,7 +383,7 @@ def _build_interval_fn_fused(
     # cand_cap is part of the cache key: compressed caps are power-of-two
     # and shrink monotonically with the census, so at most log2(n) variants
     # compile per solve (same budget as the compaction buckets).
-    donate = runtime.donation(0, 1)
+    donate = (0, 1)
     if mesh is None:
         fn = partial(_run_interval_fused, axis_name=None, lowering=lowering,
                      sort_bits=sort_bits)
@@ -438,7 +436,7 @@ def _build_interval_fn(mesh: Optional[Mesh], use_pallas: bool,
     # length and graph size per (mesh, shapes).  comp/mask are the mutated
     # state — donate so device buffers are reused in place.  cand_cap is
     # static (see _build_interval_fn_fused for the recompile budget).
-    donate = runtime.donation(0, 1)
+    donate = (0, 1)
     if mesh is None:
         fn = partial(_run_interval, axis_name=None, use_pallas=use_pallas)
         return jax.jit(fn, donate_argnums=donate)
@@ -484,7 +482,7 @@ def _device_engine(
         if np.any(source.weight.view(np.uint32) == INF32):
             raise ValueError("weights collide with the INF sentinel")
 
-    with enable_x64():
+    with jax.enable_x64():
         bundle = runtime.prepare_edges(
             source, params.partitioner, mesh, chunk=chunk)
         n, m = bundle.num_vertices, bundle.num_edges
@@ -501,7 +499,11 @@ def _device_engine(
                                        bundle.slot)
         comp_dev = put(np.arange(n, dtype=np.uint32), repl_sh)
 
+        collective = runtime.resolve_collective(params.collective)
         fused = runtime.resolve_round_kernel(params.round_kernel) == "pallas"
+        # fn_pmin: full-width pmin rounds.  Under collective="compressed"
+        # it is the dense fallback, and still takes the candidate census
+        # the compressed executables' caps are derived from.
         if fused:
             # Fused round body (DESIGN.md §9): replicated canonical-eid
             # bitmap + replicated canonical endpoints for n-scale winner
@@ -521,13 +523,12 @@ def _device_engine(
             lowering = ("pallas" if params.use_pallas
                         else "sort" if sort_bits is not None else "scatter")
             sb = sort_bits if lowering == "sort" else None
-            fn_pmin = _build_interval_fn_fused(mesh, lowering, sb)
+            fn_pmin = _build_interval_fn_fused(mesh, lowering, sb, collective)
         else:
             lowering = sb = None
             mask_dev = put(np.zeros(m0, dtype=bool), edge_sh)
-            fn_pmin = _build_interval_fn(mesh, params.use_pallas)
+            fn_pmin = _build_interval_fn(mesh, params.use_pallas, collective)
 
-        collective = runtime.resolve_collective(params.collective)
         overlap = (runtime.resolve_interval_pipeline(
             params.interval_pipeline) == 1)
         interval = max(params.check_frequency, 1)
@@ -884,7 +885,7 @@ def _build_batch_interval_fn(
     # through it, re-paying those compiles mid-request (a 256-vertex
     # lattice has ~60 combos — the old maxsize=16 silently discarded
     # most of the §12 warmup).
-    donate = runtime.donation(0, 1, 2, 3, 4, 5, 6, 7)
+    donate = (0, 1, 2, 3, 4, 5, 6, 7)
     fn = partial(_run_interval_batch, use_pallas=use_pallas,
                  contract_bits=contract_bits, election=election)
     return jax.jit(fn, donate_argnums=donate)
@@ -963,7 +964,7 @@ def warm_bucket(
             caps.append(c)
             c //= 2
     compiled = 0
-    with enable_x64():
+    with jax.enable_x64():
         for cur in caps:
             # Fresh state every iteration: the interval fn donates all
             # eight state buffers, so nothing it consumed may be reused.
@@ -1072,7 +1073,7 @@ def _solve_bucket(
         if not np.any(real & (wbits >= np.uint64(0x3F800000))):
             election = "sort"
 
-    with enable_x64():
+    with jax.enable_x64():
         src_d = jnp.asarray(batch.src)
         dst_d = jnp.asarray(batch.dst)
         key_d = jnp.asarray(batch.key)

@@ -49,7 +49,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
@@ -91,7 +90,7 @@ def _thresholds(tree_keys: np.ndarray, num_levels: int) -> np.ndarray:
 
 @functools.lru_cache(maxsize=None)
 def _build_filter_fns(num_vertices: int, mesh: Optional[Mesh],
-                      use_pallas: bool, collective: str = "pmin",
+                      collective: str = "pmin",
                       cand_cap: Optional[int] = None):
     """Compiled (labels, probe) pair for one vertex count.
 
@@ -116,7 +115,7 @@ def _build_filter_fns(num_vertices: int, mesh: Optional[Mesh],
         for j in range(thresholds.shape[0]):
             comp = minplus_ops.connected_labels(
                 t_src, t_dst, t_key <= thresholds[j], num_vertices=n,
-                init=comp, use_pallas=use_pallas, axis_name=axis_name,
+                init=comp, axis_name=axis_name,
                 collective=collective, cand_cap=cand_cap,
                 num_shards=num_shards)
             rows.append(comp)
@@ -193,9 +192,9 @@ def _run_filter(g: Graph, cand: np.ndarray, tree_pos: np.ndarray,
     p_smp, p_tree = _pad_to((smask, tmask), m_cap, (False, False))
 
     labels_fn, probe_fn = _build_filter_fns(
-        g.num_vertices, mesh, bool(params.use_pallas),
+        g.num_vertices, mesh,
         "compressed" if cand_cap is not None else "pmin", cand_cap)
-    with enable_x64():
+    with jax.enable_x64():
         labels = labels_fn(jnp.asarray(t_src), jnp.asarray(t_dst),
                            jnp.asarray(t_key), jnp.asarray(thresholds))
         keep = probe_fn(labels, jnp.asarray(thresholds),

@@ -548,7 +548,7 @@ def _build_step_fn(topo: GHSTopology, params: GHSParams,
     layer's compile cache is one of the things being measured."""
     step_core = make_superstep(topo, params, _AXIS if mesh is not None
                                else None)
-    donate = runtime.donation(0)
+    donate = (0,)
     if mesh is None:
         def f(st, do_test, gstep):
             st, act, err = step_core(st, do_test, gstep)
@@ -606,7 +606,7 @@ def _build_interval_fn(topo: GHSTopology, params: GHSParams,
             (st, jnp.int32(0), silent0.astype(jnp.int32), jnp.int32(0)))
         return st, jnp.stack([step0.astype(jnp.int32) + i, silent, err])
 
-    donate = runtime.donation(0)
+    donate = (0,)
     if mesh is None:
         return jax.jit(interval_core, donate_argnums=donate)
 

@@ -68,7 +68,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
@@ -308,7 +307,7 @@ def _anchor_tree_mask(old: IncrementalForest, new: Graph) -> np.ndarray:
 
 @functools.lru_cache(maxsize=None)
 def _build_update_fns(num_vertices: int, mesh: Optional[Mesh],
-                      use_pallas: bool, collective: str = "pmin",
+                      collective: str = "pmin",
                       cand_cap: Optional[int] = None):
     """Compiled (labels, probe) pair of the incremental pass.
 
@@ -332,15 +331,14 @@ def _build_update_fns(num_vertices: int, mesh: Optional[Mesh],
         for j in range(thresholds.shape[0]):
             comp = minplus_ops.connected_labels(
                 t_src, t_dst, t_key <= thresholds[j], num_vertices=n,
-                init=comp, use_pallas=use_pallas, axis_name=axis_name,
+                init=comp, axis_name=axis_name,
                 collective=collective, cand_cap=cand_cap,
                 num_shards=num_shards)
             rows.append(comp)
         comp, maxkey = minplus_ops.component_maxkey(
             t_src, t_dst, t_key, t_key != keys_lib.INF_KEY,
-            num_vertices=n, init=comp, use_pallas=use_pallas,
-            axis_name=axis_name, collective=collective,
-            cand_cap=cand_cap, num_shards=num_shards)
+            num_vertices=n, init=comp, axis_name=axis_name,
+            collective=collective, cand_cap=cand_cap, num_shards=num_shards)
         return jnp.stack(rows), comp, maxkey
 
     def probe_fn(labels, comp, maxkey, thresholds, src, dst, key, tree):
@@ -409,9 +407,9 @@ def _probe_candidates(g: Graph, tmask: np.ndarray, params: GHSParams,
     (p_tree,) = _pad_to((tmask,), m_cap, (False,))
 
     labels_fn, probe_fn = _build_update_fns(
-        n, mesh, bool(params.use_pallas),
+        n, mesh,
         "compressed" if cand_cap is not None else "pmin", cand_cap)
-    with enable_x64():
+    with jax.enable_x64():
         labels, comp, maxkey = labels_fn(
             jnp.asarray(t_src), jnp.asarray(t_dst), jnp.asarray(t_key),
             jnp.asarray(thresholds))

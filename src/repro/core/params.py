@@ -89,10 +89,10 @@ class GHSParams:
                                       #   election (kernels/spmv_minplus) with
                                       #   n-scale recording/hooking and one
                                       #   collective per round; with
-                                      #   use_pallas=True the election and
-                                      #   shortcut run as Pallas kernels
-                                      #   (interpret mode on CPU), otherwise
-                                      #   the scatter-free sort lowering.
+                                      #   use_pallas=True the election runs
+                                      #   as a Pallas kernel (interpreted
+                                      #   on the CPU), otherwise the
+                                      #   scatter-free sort lowering.
                                       #   Bit-identical forests either way.
     # Batched solving knobs (DESIGN.md §8) — minimum_spanning_forests only.
     batch_bucket: str = "pow2"        # pack_batch shape-bucketing policy:

@@ -291,8 +291,8 @@ def sample_device_edges(edges: "DeviceEdges", rate: float, seed: int = 0):
     key's low lane, so it is invariant to how slots are distributed.
     Padding slots (INF keys) are never sampled.
     """
-    from jax.experimental import enable_x64
-    with enable_x64():
+    import jax
+    with jax.enable_x64():
         eid = edges.key & np.uint64(0xFFFFFFFF)
         return sample_mask(seed, rate, eid) & (edges.key != keys_lib.INF_KEY)
 
@@ -685,12 +685,11 @@ def build(spec: GraphSpec, mesh=None) -> DeviceEdges:
     :func:`build_host` at any shard count.
     """
     import jax
-    from jax.experimental import enable_x64
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     num_shards = int(np.prod(mesh.devices.shape)) if mesh is not None else 1
     cap = _capacity(spec, num_shards)
-    with enable_x64():
+    with jax.enable_x64():
         ctr = np.arange(cap, dtype=np.uint64)
         if mesh is not None:
             ctr = jax.device_put(ctr, NamedSharding(mesh, P()))

@@ -24,9 +24,6 @@ This module owns the pieces that are engine-independent:
 * :class:`EngineStats` — the unified stats protocol: every engine's stats
   object derives from it so benchmarks can meter host syncs and interval
   counts uniformly.
-* :func:`donation` — ``donate_argnums`` selection: state buffers are donated
-  for in-place reuse on backends that implement donation (CPU does not;
-  donating there only emits warnings).
 * :func:`forest_from_mask` — the shared forest-extraction path from a
   canonical edge bitmap to a :class:`ForestResult`.
 * :func:`resolve_round_loop` — validation of the ``params.round_loop`` knob
@@ -133,12 +130,6 @@ class EngineStats:
     overlapped_syncs: int = 0
     speculative_intervals: int = 0
     comm_bytes: int = 0
-
-
-def donation(*argnums: int) -> Tuple[int, ...]:
-    """``donate_argnums`` for mutated state buffers, or () on backends
-    (CPU) that do not implement donation and would only warn."""
-    return argnums if jax.default_backend() != "cpu" else ()
 
 
 def interval_loop(

@@ -20,7 +20,7 @@ _PAD_SEG = np.int32(0x7FFFFFF0)
                                              "interpret"))
 def segment_min_sorted(
     val: jnp.ndarray, seg: jnp.ndarray, *, num_segments: int,
-    block: int = 1024, interpret: bool = True,
+    block: int = 1024, interpret: "bool | None" = None,
 ) -> jnp.ndarray:
     """Per-segment min for SORTED ``seg`` via the Pallas scan kernel.
 
@@ -48,7 +48,7 @@ def segment_min_sorted(
                                              "interpret"))
 def segment_min(
     val: jnp.ndarray, seg: jnp.ndarray, *, num_segments: int,
-    use_pallas: bool = False, interpret: bool = True,
+    use_pallas: bool = False, interpret: "bool | None" = None,
     order: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Per-segment min; unsorted input. Pallas path sorts then scans.
@@ -71,7 +71,7 @@ def segment_min(
                                              "interpret"))
 def segment_min64_sorted(
     key: jnp.ndarray, seg: jnp.ndarray, *, num_segments: int,
-    block: int = 1024, interpret: bool = True,
+    block: int = 1024, interpret: "bool | None" = None,
 ) -> jnp.ndarray:
     """Per-segment min over SORTED packed uint64 keys via the pair-lex
     Pallas scan — the key is split into uint32 lanes so the kernel stays in
@@ -99,7 +99,7 @@ def segment_min64_sorted(
                                              "interpret"))
 def segment_min64(
     key: jnp.ndarray, seg: jnp.ndarray, *, num_segments: int,
-    use_pallas: bool = False, interpret: bool = True,
+    use_pallas: bool = False, interpret: "bool | None" = None,
     order: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Per-segment min over packed uint64 keys; unsorted input.

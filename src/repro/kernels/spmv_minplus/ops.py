@@ -10,8 +10,9 @@ Three lowerings of the SAME masked min-plus election, selected statically:
   :func:`sort_gate` on the bit budget.
 * ``"pallas"``  — the :mod:`.spmv_minplus` masked pair-lex scan kernel
   (sort by fragment, tiled masked segmented min-scan, conflict-free
-  run-end extraction).  The accelerator lowering; interpret mode keeps the
-  exact kernel semantics testable on CPU CI.
+  run-end extraction).  The accelerator lowering: compiled by Mosaic on
+  the TPU, interpreted on the CPU so tests check the exact kernel
+  semantics (:func:`repro.kernels.interpret_mode`).
 
 All three are exact min-reductions over identical packed keys, so they are
 bit-identical by construction — tests enforce it under hypothesis-generated
@@ -28,8 +29,7 @@ import numpy as np
 from repro.core import keys as keys_lib
 from repro.core import union_find
 from repro.kernels.spmv_minplus import ref
-from repro.kernels.spmv_minplus.spmv_minplus import (
-    masked_minplus_scan, pointer_jump)
+from repro.kernels.spmv_minplus.spmv_minplus import masked_minplus_scan
 
 INF_U64 = keys_lib.INF_KEY
 _PAD_SEG = np.int32(0x7FFFFFF0)
@@ -122,7 +122,7 @@ def elect(
     cs: jnp.ndarray, cd: jnp.ndarray, key: jnp.ndarray, *,
     num_segments: int, lowering: str = "scatter",
     sort_bits: "tuple[int, int] | None" = None,
-    block: int = 1024, interpret: bool = True,
+    block: int = 1024, interpret: "bool | None" = None,
 ) -> jnp.ndarray:
     """Per-fragment minimum-outgoing-edge election over packed uint64 keys.
 
@@ -145,13 +145,11 @@ def elect(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_vertices", "axis_name", "use_pallas", "interpret",
-    "collective", "cand_cap", "num_shards"))
+    "num_vertices", "axis_name", "collective", "cand_cap", "num_shards"))
 def connected_labels(
     src: jnp.ndarray, dst: jnp.ndarray, active: jnp.ndarray, *,
     num_vertices: int, init: "jnp.ndarray | None" = None,
     axis_name: "str | None" = None,
-    use_pallas: bool = False, interpret: bool = True,
     collective: str = "pmin", cand_cap: "int | None" = None,
     num_shards: int = 1,
 ) -> jnp.ndarray:
@@ -214,8 +212,7 @@ def connected_labels(
                     cap=cand_cap, num_shards=num_shards)
             else:
                 parent = jax.lax.pmin(parent, axis_name)
-        comp = shortcut_relabel(parent.astype(jnp.int32), comp,
-                                use_pallas=use_pallas, interpret=interpret)
+        comp = shortcut_relabel(parent.astype(jnp.int32), comp)
         _, _, alive2 = crossing(comp)
         return comp, alive_any(alive2)
 
@@ -228,14 +225,12 @@ def connected_labels(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_vertices", "axis_name", "use_pallas", "interpret",
-    "collective", "cand_cap", "num_shards"))
+    "num_vertices", "axis_name", "collective", "cand_cap", "num_shards"))
 def component_maxkey(
     src: jnp.ndarray, dst: jnp.ndarray, key: jnp.ndarray,
     active: jnp.ndarray, *,
     num_vertices: int, init: "jnp.ndarray | None" = None,
     axis_name: "str | None" = None,
-    use_pallas: bool = False, interpret: bool = True,
     collective: str = "pmin", cand_cap: "int | None" = None,
     num_shards: int = 1,
 ) -> "tuple[jnp.ndarray, jnp.ndarray]":
@@ -264,8 +259,7 @@ def component_maxkey(
     n = num_vertices
     comp = connected_labels(
         src, dst, active, num_vertices=n, init=init, axis_name=axis_name,
-        use_pallas=use_pallas, interpret=interpret, collective=collective,
-        cand_cap=cand_cap, num_shards=num_shards)
+        collective=collective, cand_cap=cand_cap, num_shards=num_shards)
     # Active edges never cross components at convergence, so one endpoint
     # names the segment; inactive/padding lanes are dropped out of range.
     seg = comp[jnp.clip(src, 0, n - 1)]
@@ -273,23 +267,16 @@ def component_maxkey(
         jnp.where(active, seg, n)
     ].max(jnp.where(active, key, jnp.uint64(0)), mode="drop")
     if axis_name is not None:
-        mx = jax.lax.pmax(mx, axis_name)
+        from repro.sharding import collectives
+        mx = collectives.pmax(mx, axis_name)
     return comp, mx[comp]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def shortcut_relabel(
-    parent: jnp.ndarray, comp: jnp.ndarray, *,
-    use_pallas: bool = False, interpret: bool = True,
-) -> jnp.ndarray:
-    """Fused pointer-jumping shortcut + fragment relabel.
-
-    Equivalent to ``union_find.pointer_double(parent)[comp]``; the Pallas
-    path runs all doubling steps and the relabel in one VMEM-resident
-    launch.
-    """
-    if not use_pallas:
-        return ref.shortcut_relabel(parent, comp)
-    # The kernel computes in uint32 lanes; callers carry int32 labels
-    # through while_loops, so restore the label dtype.
-    return pointer_jump(parent, comp, interpret=interpret).astype(comp.dtype)
+@jax.jit
+def shortcut_relabel(parent: jnp.ndarray, comp: jnp.ndarray) -> jnp.ndarray:
+    """Pointer-jumping shortcut + fragment relabel,
+    ``union_find.pointer_double(parent)[comp]``, on every lowering: the
+    doubling steps are data-dependent gathers over all ``n`` labels, which
+    stay in XLA (no Pallas kernel — see the :mod:`.spmv_minplus`
+    docstring)."""
+    return ref.shortcut_relabel(parent, comp)

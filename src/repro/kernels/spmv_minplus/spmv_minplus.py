@@ -1,46 +1,38 @@
-"""Pallas TPU kernels: fused semiring Borůvka round body (DESIGN.md §9).
+"""Pallas TPU kernel: the masked min-plus election of the fused Borůvka
+round body (DESIGN.md §9).
 
 The per-round MOE election is a masked min-plus segmented SpMV: for every
 fragment *f*, ``best[f] = min over incident live edges of (weight ‖ edge-id)``
 in the (min, +) semiring over packed keys, where a *live* edge is one whose
-endpoints lie in different fragments.  Two kernels cover the round body's
-cap-scale and n-scale hot loops:
+endpoints lie in different fragments.
 
-* :func:`masked_minplus_scan` — the SpMV reduction: a segmented pair-lex
-  min-scan over (weight-bits, edge-id) uint32 lanes with IN-KERNEL masking
-  of dead edges — the ``alive``/``where`` chain of the XLA round body never
-  materializes a masked key array in HBM; each tile applies the mask on the
-  fly and joins dead lanes to the scan as the semiring identity (INF).
-  Extends ``kernels/segment_min``'s pair-lex scan (same Hillis–Steele
-  recurrence, same SMEM carry across the sequential tiled grid) with the
-  fused mask lanes.
-* :func:`pointer_jump` — the merge shortcut: ⌈log2 n⌉ pointer-doubling
-  gathers fused with the final fragment relabel ``parent*[comp]`` in one
-  VMEM-resident launch, instead of log n + 1 separate XLA gather dispatches.
+:func:`masked_minplus_scan` is that reduction: a segmented pair-lex
+min-scan over (weight-bits, edge-id) uint32 lanes with IN-KERNEL masking of
+dead edges — the ``alive``/``where`` chain of the XLA round body never
+materializes a masked key array in HBM; each tile applies the mask on the
+fly and joins dead lanes to the scan as the semiring identity (INF).  It
+runs ``kernels/segment_min``'s tiled pair-lex scan (same Hillis–Steele
+recurrence, same VMEM carry across the sequential grid) behind the mask.
 
-Both kernels default to ``interpret=True`` so CPU CI validates the exact
-kernel semantics bit-for-bit (the repo-wide policy for kernel packages);
-on TPU the same code compiles with ``interpret=False``.  The hook phase
-between them is a single conflict-light n-scale scatter-min that stays in
-XLA — see DESIGN.md §9 for why fragment-pair dedup (e.g. via the
-``kernels/edge_hash`` probe) is unnecessary in this formulation.
+The merge shortcut that follows the election stays in XLA
+(``union_find.pointer_double``): pointer doubling is a data-dependent
+gather over all ``n`` labels, which Mosaic cannot lower inside a kernel
+and which does not fit VMEM at engine scales.  The hook phase is a single
+conflict-light n-scale scatter-min, also in XLA — see DESIGN.md §9.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-INF_U32 = 0xFFFFFFFF           # python int: safe to close over
-SENTINEL_SEG = -2              # carry init; never a real segment id
+from repro.kernels.segment_min.segment_min import (
+    INF_U32, init_carry, scan_tile, tiled_scan_call)
 
 
 def _minplus_kernel(seg_ref, oth_ref, hi_ref, lo_ref, ohi_ref, olo_ref,
-                    carry_seg, carry_hi, carry_lo, *, block):
+                    carry_seg, carry_hi, carry_lo):
     """Masked segmented pair-lex min-scan tile (see module docstring).
 
     ``seg`` is the reducing-side fragment label (sorted), ``oth`` the other
@@ -50,52 +42,25 @@ def _minplus_kernel(seg_ref, oth_ref, hi_ref, lo_ref, ohi_ref, olo_ref,
     ops layer pads both with the same sentinel), so they are dead by the
     same test and need no third sentinel convention.
     """
-    i = pl.program_id(0)
+    init_carry(carry_seg, (carry_hi, carry_lo))
     inf = jnp.uint32(INF_U32)
-    sentinel = jnp.int32(SENTINEL_SEG)
-
-    @pl.when(i == 0)
-    def _init():
-        carry_seg[0] = sentinel
-        carry_hi[0] = inf
-        carry_lo[0] = inf
-
     seg = seg_ref[...]
-    oth = oth_ref[...]
     hi = hi_ref[...]
     lo = lo_ref[...]
     # In-kernel masking: dead edges (endpoints in one fragment, or the INF
     # padding key) join the scan as the semiring identity.
-    live = (seg != oth) & jnp.logical_not((hi == inf) & (lo == inf))
-    hi = jnp.where(live, hi, inf)
-    lo = jnp.where(live, lo, inf)
-    idx = jax.lax.iota(jnp.int32, block)
-    # Segmented Hillis–Steele pair-lex min-scan within the tile.
-    shift = 1
-    while shift < block:
-        shi = jnp.where(idx >= shift, jnp.roll(hi, shift), inf)
-        slo = jnp.where(idx >= shift, jnp.roll(lo, shift), inf)
-        sseg = jnp.where(idx >= shift, jnp.roll(seg, shift), sentinel)
-        take = (sseg == seg) & ((shi < hi) | ((shi == hi) & (slo < lo)))
-        hi = jnp.where(take, shi, hi)
-        lo = jnp.where(take, slo, lo)
-        shift *= 2
-    # Fold the cross-tile carry into this tile's first run.
-    ch, cl = carry_hi[0], carry_lo[0]
-    take = (seg == carry_seg[0]) & ((ch < hi) | ((ch == hi) & (cl < lo)))
-    hi = jnp.where(take, ch, hi)
-    lo = jnp.where(take, cl, lo)
+    live = (seg != oth_ref[...]) & jnp.logical_not((hi == inf) & (lo == inf))
+    hi, lo = scan_tile(seg, (jnp.where(live, hi, inf),
+                             jnp.where(live, lo, inf)),
+                       carry_seg, (carry_hi, carry_lo))
     ohi_ref[...] = hi
     olo_ref[...] = lo
-    carry_seg[0] = seg[block - 1]
-    carry_hi[0] = hi[block - 1]
-    carry_lo[0] = lo[block - 1]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def masked_minplus_scan(
     seg: jnp.ndarray, oth: jnp.ndarray, hi: jnp.ndarray, lo: jnp.ndarray,
-    *, block: int = 1024, interpret: bool = True,
+    *, block: int = 1024, interpret: "bool | None" = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Masked inclusive segmented lex-min scan along sorted ``seg`` runs.
 
@@ -103,67 +68,5 @@ def masked_minplus_scan(
     contribute the identity.  The run-end elements hold each segment's
     masked min; the ops layer finalizes with a conflict-free scatter.
     """
-    assert seg.shape == oth.shape == hi.shape == lo.shape and seg.ndim == 1
-    m = seg.shape[0]
-    assert m % block == 0, "caller pads to a block multiple"
-    grid = (m // block,)
-    return pl.pallas_call(
-        functools.partial(_minplus_kernel, block=block),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.uint32),
-            jax.ShapeDtypeStruct((m,), jnp.uint32),
-        ],
-        scratch_shapes=[
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SMEM((1,), jnp.uint32),
-            pltpu.SMEM((1,), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(seg, oth, hi, lo)
-
-
-def _jump_kernel(parent_ref, comp_ref, out_ref, *, num_steps):
-    """Pointer-doubling shortcut + relabel, entirely VMEM-resident.
-
-    ``num_steps`` doublings fully compress the strictly-decreasing hook
-    forest (hook_min guarantees parent <= id), then the fragment labels are
-    re-pointed through the compressed parent in the same launch.
-    """
-    p = parent_ref[...]
-
-    def body(_, p):
-        return jnp.take(p, p.astype(jnp.int32), mode="clip")
-
-    p = jax.lax.fori_loop(0, num_steps, body, p)
-    out_ref[...] = jnp.take(p, comp_ref[...].astype(jnp.int32), mode="clip")
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def pointer_jump(
-    parent: jnp.ndarray, comp: jnp.ndarray, *, interpret: bool = True,
-) -> jnp.ndarray:
-    """Fused full path compression + relabel: ``pointer_double(parent)[comp]``.
-
-    Single-block launch: the (n,) parent and label arrays stay in VMEM for
-    all ⌈log2 n⌉ gather steps (n ≤ ~1M uint32 fits the ~16 MB VMEM budget;
-    the engines' replicated fragment-label arrays are far below that).
-    """
-    assert parent.ndim == 1 and comp.ndim == 1
-    n = parent.shape[0]
-    num_steps = max(1, math.ceil(math.log2(max(n, 2))))
-    return pl.pallas_call(
-        functools.partial(_jump_kernel, num_steps=num_steps),
-        out_shape=jax.ShapeDtypeStruct(comp.shape, jnp.uint32),
-        interpret=interpret,
-    )(parent.astype(jnp.uint32), comp.astype(jnp.uint32))
+    return tiled_scan_call(_minplus_kernel, seg, (oth, hi, lo), 2,
+                           block=block, interpret=interpret)

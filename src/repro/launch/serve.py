@@ -535,6 +535,7 @@ def run_poisson(
 # ---------------------------------------------------------------------------
 
 def main(argv=None):
+    from repro import platform
     from repro.core import generators, kruskal_ref
 
     ap = argparse.ArgumentParser(
@@ -555,6 +556,7 @@ def main(argv=None):
                     help="check every served forest against the Kruskal "
                          "oracle")
     args = ap.parse_args(argv)
+    platform.use_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 64)
         args.max_vertices = min(args.max_vertices, 64)

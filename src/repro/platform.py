@@ -1,26 +1,30 @@
-"""Backend pinning for benchmarks and CI legs.
+"""Process-level JAX set-up: backend pinning and the compile cache.
 
-Every bench leg (and the subprocess children the shard sweeps spawn) must
-pin its backend EXPLICITLY — a benchmark that silently lands on a different
-platform, device count, or x64 mode produces numbers that cannot be
-compared across runs.  This module is the one home for that pinning:
-
-* :func:`set_platform` — force the jax platform (``cpu``/``gpu``/``tpu``),
-  plus the allocator flags a GPU run wants pinned and the platform's
-  latency-hiding / async-collective flags (:func:`latency_hiding_flags`),
-  so accelerator bench legs get compute/collective overlap for free.
+* :func:`set_platform` — force the jax platform (``cpu``/``tpu``).  It sets
+  no XLA flags: an unknown flag in ``XLA_FLAGS`` aborts the process at
+  backend start-up.
 * :func:`force_host_device_count` — emulate an N-device host (the
   ``--xla_force_host_platform_device_count`` flag the multi-shard tests
-  and sweeps rely on).
+  and CPU sweeps rely on).
 * :func:`set_debug_nan` / :func:`set_x64` — debugging & width toggles.
 * :func:`pin` — one-stop shop used by ``benchmarks/common.py``.
+* :func:`use_compile_cache` — JAX's persistent compilation cache, placed
+  from outside (``JAX_COMPILATION_CACHE_DIR``) or at a fixed path inside
+  the checkout.
 
-All of these must run BEFORE jax initializes its backends; each helper
+The pinning helpers must run BEFORE jax initializes its backends; each
 raises if called too late rather than silently doing nothing.
 """
 from __future__ import annotations
 
 import os
+
+# Fixed cache path inside the checkout (git-ignored).  The path is part of
+# what a later run must find again, so it never depends on the process,
+# the time or a temporary name.
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
 def _jax_initialized() -> bool:
@@ -50,30 +54,13 @@ def _append_xla_flags(flag: str) -> None:
     os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
 
 
-def latency_hiding_flags(platform: str = "tpu") -> str:
-    """XLA latency-hiding / async-collective flags for ``platform``
-    (re-exported from :mod:`repro.sharding.collectives` so bench legs can
-    pin overlap without importing the sharding layer)."""
-    from repro.sharding import collectives
-    return collectives.latency_hiding_flags(platform)
-
-
 def set_platform(platform: str = "cpu") -> None:
-    """Force the jax platform; pins allocator + latency-hiding flags
-    alongside (accelerator legs get compute/collective overlap for free —
-    the CPU container has no such flags and skips)."""
-    if platform not in ("cpu", "gpu", "tpu"):
+    """Force the jax platform."""
+    if platform not in ("cpu", "tpu"):
         raise ValueError(f"unknown platform {platform!r}")
     _require_uninitialized("platform")
-    for flag in latency_hiding_flags(platform).split():
-        _append_xla_flags(flag)
     import jax
     jax.config.update("jax_platform_name", platform)
-    if platform == "gpu":
-        # Deterministic memory behavior for benching: no growth-on-demand
-        # rescans mid-run.
-        os.environ.setdefault("XLA_PYTHON_CLIENT_PREALLOCATE", "false")
-        os.environ.setdefault("XLA_PYTHON_CLIENT_ALLOCATOR", "platform")
 
 
 def force_host_device_count(n: int) -> None:
@@ -85,7 +72,7 @@ def force_host_device_count(n: int) -> None:
 
 
 def set_x64(enable: bool = True) -> None:
-    """Global x64 default.  The engines scope their own ``enable_x64``
+    """Global x64 default.  The engines scope their own ``jax.enable_x64``
     contexts, so benches normally leave this alone; kernels-only legs that
     feed uint64 keys straight into ops use it."""
     import jax
@@ -109,3 +96,17 @@ def pin(platform: str | None = None, host_devices: int | None = None,
         set_x64(x64)
     if debug_nan is not None:
         set_debug_nan(debug_nan)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already taken it
+    as ``jax_compilation_cache_dir`` and nothing is changed here.
+    Otherwise the cache goes to :data:`CACHE_DIR`, ``.jax_cache/`` at the
+    root of the checkout."""
+    import jax
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR

@@ -23,11 +23,6 @@ the exchange is sized by what changed, not by the vertex count.
 the data-parallel reduction XLA inserts (halving DP all-reduce bytes);
 the residual rides in the optimizer state so the update is unbiased over
 time.
-
-``latency_hiding_flags`` returns the XLA flags that enable the
-latency-hiding scheduler (compute/collective overlap) for TPU *and* GPU
-runs; :func:`repro.platform.set_platform` exports them, the CPU container
-ignores them.
 """
 from __future__ import annotations
 
@@ -47,6 +42,44 @@ def resolve_collective(collective: str) -> str:
         raise ValueError(
             f"unknown collective {collective!r}; options: {COLLECTIVES}")
     return collective
+
+
+def _lanes(x):
+    """(high, low) uint32 lanes of a uint64 array."""
+    return ((x >> jnp.uint64(32)).astype(jnp.uint32),
+            (x & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
+
+
+def _join(hi, lo):
+    return (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
+
+
+def pmin(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """``lax.pmin`` that also lowers for uint64 on the TPU.
+
+    XLA:TPU all-reduces 64-bit values by sum only, so a uint64 min runs as
+    two uint32 reductions: the high lane first, then the low lane among
+    the shards that hold the winning high lane.  Unsigned order is the
+    lexicographic order of the lanes, so the result is bit-identical."""
+    if x.dtype != jnp.uint64:
+        return jax.lax.pmin(x, axis_name)
+    hi, lo = _lanes(x)
+    hi_min = jax.lax.pmin(hi, axis_name)
+    lo_min = jax.lax.pmin(
+        jnp.where(hi == hi_min, lo, jnp.uint32(0xFFFFFFFF)), axis_name)
+    return _join(hi_min, lo_min)
+
+
+def pmax(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """``lax.pmax`` that also lowers for uint64 on the TPU (see
+    :func:`pmin`)."""
+    if x.dtype != jnp.uint64:
+        return jax.lax.pmax(x, axis_name)
+    hi, lo = _lanes(x)
+    hi_max = jax.lax.pmax(hi, axis_name)
+    lo_max = jax.lax.pmax(jnp.where(hi == hi_max, lo, jnp.uint32(0)),
+                          axis_name)
+    return _join(hi_max, lo_max)
 
 
 def pmin_compressed(
@@ -84,7 +117,7 @@ def pmin_compressed(
     overflow = jax.lax.pmax((count > cap).astype(jnp.int32), axis_name) > 0
 
     def full(x):
-        return jax.lax.pmin(x, axis_name)
+        return pmin(x, axis_name)
 
     def ring(x):
         pos = jnp.cumsum(has.astype(jnp.int32)) - 1
@@ -139,38 +172,3 @@ def compress_tree(grads, residual):
     new_res = jax.tree.map(lambda p: p[1], pairs,
                            is_leaf=lambda x: isinstance(x, tuple))
     return comp_g, new_res
-
-
-LATENCY_HIDING_FLAGS_TPU = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true "
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
-    "--xla_tpu_overlap_compute_collective_tc=true "
-)
-
-LATENCY_HIDING_FLAGS_GPU = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true "
-    "--xla_gpu_enable_pipelined_all_reduce=true "
-    "--xla_gpu_enable_pipelined_all_gather=true "
-    "--xla_gpu_enable_pipelined_reduce_scatter=true "
-    "--xla_gpu_enable_while_loop_double_buffering=true "
-)
-
-
-def latency_hiding_flags(platform: str = "tpu") -> str:
-    """XLA latency-hiding / async-collective flags for ``platform``.
-
-    ``"tpu"`` enables the latency-hiding scheduler + async collective
-    fusion; ``"gpu"`` the GPU scheduler, prioritized async streams, and
-    pipelined collectives (plus while-loop double buffering, which pairs
-    with the runtime's double-buffered intervals — DESIGN.md §11).
-    ``"cpu"`` has no such flags and returns the empty string.
-    """
-    if platform == "tpu":
-        return LATENCY_HIDING_FLAGS_TPU
-    if platform == "gpu":
-        return LATENCY_HIDING_FLAGS_GPU
-    if platform == "cpu":
-        return ""
-    raise ValueError(f"unknown platform {platform!r}")

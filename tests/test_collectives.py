@@ -48,7 +48,6 @@ def test_pmin_compressed_bit_identity_1_2_4_8_shards():
     out = run_child("""
 import json
 import numpy as np, jax, jax.numpy as jnp
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec as P
 from repro.compat import make_mesh, shard_map
 from repro.sharding import collectives
@@ -58,7 +57,7 @@ rows = []
 for shards in (1, 2, 4, 8):
     mesh = make_mesh((shards,), ("x",))
     for dtype, inf in ((jnp.uint32, 2**32 - 1), (jnp.uint64, 2**64 - 1)):
-        with enable_x64():
+        with jax.enable_x64():
             default = jnp.full((N,), inf, dtype)
             rng = np.random.default_rng(shards)
             def case(n_improved, equal=False):
@@ -323,17 +322,32 @@ def test_pmin_compressed_single_shard_paths(_src_path):
         assert np.array_equal(got, x[0]), cap
 
 
-def test_latency_hiding_flags(_src_path):
-    from repro.sharding import collectives
-
-    tpu = collectives.latency_hiding_flags("tpu")
-    gpu = collectives.latency_hiding_flags("gpu")
-    assert "latency_hiding_scheduler" in tpu
-    assert "latency_hiding_scheduler" in gpu
-    assert "while_loop_double_buffering" in gpu
-    assert collectives.latency_hiding_flags("cpu") == ""
-    with pytest.raises(ValueError):
-        collectives.latency_hiding_flags("dsp")
-    # the platform façade re-exports the same flag source
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(_src_path, tmp_path, monkeypatch, from_env):
+    """The persistent cache goes where JAX_COMPILATION_CACHE_DIR says and
+    the code sets no other path; without it, to the fixed in-checkout
+    directory.  The XLA flags stay untouched either way."""
+    import jax
     from repro import platform as platform_lib
-    assert platform_lib.latency_hiding_flags("gpu") == gpu
+
+    before = jax.config.jax_compilation_cache_dir
+    flags = os.environ.get("XLA_FLAGS")
+    env_dir = str(tmp_path / "cache")
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = platform_lib.use_compile_cache()
+        if from_env:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == platform_lib.CACHE_DIR
+            assert os.path.basename(got) == ".jax_cache"
+            assert os.path.isfile(os.path.join(
+                os.path.dirname(got), "src", "repro", "platform.py"))
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert os.environ.get("XLA_FLAGS") == flags

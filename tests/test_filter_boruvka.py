@@ -143,10 +143,10 @@ def test_empty_sample_keeps_isolated_vertex_bridge():
 
 def test_sampler_numpy_jnp_identical_and_slice_invariant():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    import jax
     eid = np.arange(5000, dtype=np.uint64)
     m_np = np.asarray(pipeline.sample_mask(3, 0.37, eid))
-    with enable_x64():
+    with jax.enable_x64():
         m_j = np.asarray(pipeline.sample_mask(3, 0.37, jnp.asarray(eid)))
     assert np.array_equal(m_np, m_j)
     # per-edge decisions do not depend on which shard holds the edge:
@@ -167,10 +167,10 @@ def test_sampler_numpy_jnp_identical_and_slice_invariant():
 
 def test_sampler_fixed_k_exact_size():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    import jax
     eid = np.arange(700, dtype=np.uint64)
     m_np = pipeline.sample_mask_fixed_k(np, 5, 123, eid)
-    with enable_x64():
+    with jax.enable_x64():
         m_j = np.asarray(
             pipeline.sample_mask_fixed_k(jnp, 5, 123, jnp.asarray(eid)))
     assert np.array_equal(m_np, m_j)

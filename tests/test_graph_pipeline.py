@@ -234,7 +234,6 @@ def test_preprocess_general_path_matches_oracle():
     ``scale`` argument alone, so it is exercised directly on small arrays."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from repro.core.pipeline import _preprocess_device
 
     rng = np.random.default_rng(3)
@@ -245,7 +244,7 @@ def test_preprocess_general_path_matches_oracle():
     dst[1::5] = dst[::5][:len(dst[1::5])]   # extra collisions
     src[1::5] = src[::5][:len(src[1::5])]
     w = (rng.integers(0, 1 << 23, cap).astype(np.float32) + 0.5) * 2.0 ** -23
-    with enable_x64():
+    with jax.enable_x64():
         s, d, k, cnt = jax.jit(
             lambda s, d, w, c: _preprocess_device(
                 s, d, w, c, num_samples=m, cap=cap, scale=18)

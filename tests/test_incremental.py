@@ -455,7 +455,7 @@ def _oracle_maxkey(n, src, dst, key, active):
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
 def test_component_maxkey_matches_union_find(seed):
-    from jax.experimental import enable_x64
+    import jax
     rng = np.random.default_rng(seed)
     n = int(rng.integers(2, 100))
     m = int(rng.integers(0, 300))
@@ -463,13 +463,13 @@ def test_component_maxkey_matches_union_find(seed):
     dst = rng.integers(0, n, m).astype(np.int32)
     key = rng.integers(1, 2**63, size=m, dtype=np.uint64)
     active = rng.random(m) < 0.5
-    with enable_x64():
+    with jax.enable_x64():
         comp, mk = minplus_ops.component_maxkey(
             src, dst, np.asarray(key), active, num_vertices=n)
     want = _oracle_maxkey(n, src, dst, key, active)
     assert np.array_equal(np.asarray(mk), want), seed
     # warm-start from the converged labels: bit-identical result
-    with enable_x64():
+    with jax.enable_x64():
         comp2, mk2 = minplus_ops.component_maxkey(
             src, dst, np.asarray(key), active, num_vertices=n,
             init=comp)
@@ -479,12 +479,12 @@ def test_component_maxkey_matches_union_find(seed):
 
 def test_component_maxkey_padding_inert():
     """PAD_VERTEX lanes with active=False never reach the scatter-max."""
-    from jax.experimental import enable_x64
+    import jax
     src = np.asarray([0, 2, PAD_VERTEX], np.int32)
     dst = np.asarray([1, 3, PAD_VERTEX], np.int32)
     key = np.asarray([7, 9, 2**63 - 1], np.uint64)
     active = np.asarray([True, True, False])
-    with enable_x64():
+    with jax.enable_x64():
         comp, mk = minplus_ops.component_maxkey(
             src, dst, key, active, num_vertices=5)
     assert np.array_equal(np.asarray(comp), [0, 0, 2, 2, 4])

@@ -15,7 +15,7 @@ def test_segment_min_sweep(m, s):
     seg = np.sort(RNG.integers(0, s, m)).astype(np.int32)
     val = RNG.integers(0, 2**32 - 2, m, dtype=np.uint32)
     got = ops.segment_min_sorted(jnp.asarray(val), jnp.asarray(seg),
-                                 num_segments=s, block=512)
+                                 num_segments=s, block=1024)
     want = ref.segment_min(jnp.asarray(val), jnp.asarray(seg), s)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
@@ -47,12 +47,11 @@ def test_segment_min_precomputed_order():
 @pytest.mark.parametrize("m,s", [(512, 3), (2100, 64), (1024, 1)])
 def test_segment_min64_packed_key_sweep(m, s):
     """Pair-lex Pallas scan over packed uint64 keys == uint64 scatter-min."""
-    from jax.experimental import enable_x64
     from repro.kernels.segment_min import ops, ref
     seg = RNG.integers(0, s, m).astype(np.int32)
     key = ((RNG.integers(0, 2**31, m).astype(np.uint64) << np.uint64(32))
            | RNG.integers(0, 2**32 - 1, m).astype(np.uint64))
-    with enable_x64():
+    with jax.enable_x64():
         got = ops.segment_min64(jnp.asarray(key), jnp.asarray(seg),
                                 num_segments=s, use_pallas=True)
         want = ref.segment_min64(jnp.asarray(key), jnp.asarray(seg), s)
@@ -60,16 +59,15 @@ def test_segment_min64_packed_key_sweep(m, s):
 
 
 def test_segmented_min2_scan_matches_oracle():
-    from jax.experimental import enable_x64
     from repro.kernels.segment_min import ref
     from repro.kernels.segment_min.segment_min import segmented_min2_scan
-    m = 1024
+    m = 4096
     seg = np.sort(RNG.integers(0, 9, m)).astype(np.int32)
     hi = RNG.integers(0, 50, m, dtype=np.uint32)     # many hi-lane ties
     lo = RNG.integers(0, 2**32 - 2, m, dtype=np.uint32)
-    with enable_x64():
+    with jax.enable_x64():
         gh, gl = segmented_min2_scan(jnp.asarray(seg), jnp.asarray(hi),
-                                     jnp.asarray(lo), block=512)
+                                     jnp.asarray(lo), block=1024)
         wh, wl = ref.segmented_min2_scan(jnp.asarray(seg), jnp.asarray(hi),
                                          jnp.asarray(lo))
     assert np.array_equal(np.asarray(gh), np.asarray(wh))
@@ -228,7 +226,6 @@ def test_selective_scan_step_consistency():
 def test_segment_min_empty_input_returns_inf():
     """m == 0 must return INF sentinels without reaching a zero-grid
     pallas_call (interpret mode tolerates one, compiled lowering does not)."""
-    from jax.experimental import enable_x64
     from repro.kernels.segment_min import ops
     empty32 = jnp.zeros(0, jnp.uint32)
     emptyseg = jnp.zeros(0, jnp.int32)
@@ -236,7 +233,7 @@ def test_segment_min_empty_input_returns_inf():
     assert np.array_equal(np.asarray(got), np.full(7, 0xFFFFFFFF, np.uint32))
     got = ops.segment_min(empty32, emptyseg, num_segments=7, use_pallas=True)
     assert np.array_equal(np.asarray(got), np.full(7, 0xFFFFFFFF, np.uint32))
-    with enable_x64():
+    with jax.enable_x64():
         got = ops.segment_min64_sorted(jnp.zeros(0, jnp.uint64), emptyseg,
                                        num_segments=5)
         assert np.array_equal(np.asarray(got),
@@ -259,7 +256,6 @@ def test_segment_min_fully_masked_inputs_return_inf():
     """All-PAD_VERTEX segments (every lane is engine padding): every real
     segment must come back INF — the sentinel run may not leak into any
     output slot."""
-    from jax.experimental import enable_x64
     from repro.core.graph import PAD_VERTEX
     from repro.kernels.segment_min import ops
     m, s = 1000, 13
@@ -268,7 +264,7 @@ def test_segment_min_fully_masked_inputs_return_inf():
     got = ops.segment_min(jnp.asarray(val), jnp.asarray(seg),
                           num_segments=s, use_pallas=True)
     assert np.array_equal(np.asarray(got), np.full(s, 0xFFFFFFFF, np.uint32))
-    with enable_x64():
+    with jax.enable_x64():
         key = np.full(m, ops.INF_U64, np.uint64)
         got = ops.segment_min64(jnp.asarray(key), jnp.asarray(seg),
                                 num_segments=s, use_pallas=True)
@@ -312,17 +308,15 @@ def _election_case(rng, *, all_equal=False, dup_keys=False, ragged=False):
 
 
 def _assert_elect_lowerings_agree(n, cs, cd, key):
-    from jax.experimental import enable_x64
     from repro.kernels.spmv_minplus import ops
     m = key.shape[0]
-    with enable_x64():
+    with jax.enable_x64():
         args = (jnp.asarray(cs), jnp.asarray(cd), jnp.asarray(key))
         want = ops.elect(*args, num_segments=n, lowering="scatter")
         sort_bits = ops.sort_gate(n, max(m, 1))
         got_sort = ops.elect(*args, num_segments=n, lowering="sort",
                              sort_bits=sort_bits)
-        got_pallas = ops.elect(*args, num_segments=n, lowering="pallas",
-                               block=128)
+        got_pallas = ops.elect(*args, num_segments=n, lowering="pallas")
         assert np.array_equal(np.asarray(want), np.asarray(got_sort))
         assert np.array_equal(np.asarray(want), np.asarray(got_pallas))
 
@@ -355,11 +349,10 @@ if HAVE_HYPOTHESIS:
 def test_masked_minplus_scan_matches_masked_oracle():
     """The in-kernel mask == pre-masking the lanes then running the
     unmasked pair-lex scan oracle."""
-    from jax.experimental import enable_x64
     from repro.kernels.segment_min import ref as segref
     from repro.kernels.spmv_minplus.spmv_minplus import masked_minplus_scan
     rng = np.random.default_rng(7)
-    m = 1024
+    m = 4096
     seg = np.sort(rng.integers(0, 11, m)).astype(np.int32)
     oth = rng.integers(0, 11, m).astype(np.int32)
     hi = rng.integers(0, 40, m, dtype=np.uint32)       # many hi-lane ties
@@ -367,10 +360,10 @@ def test_masked_minplus_scan_matches_masked_oracle():
     inf = np.uint32(0xFFFFFFFF)
     hi[rng.random(m) < 0.1] = inf                      # INF padding lanes
     lo[hi == inf] = inf
-    with enable_x64():
+    with jax.enable_x64():
         gh, gl = masked_minplus_scan(
             jnp.asarray(seg), jnp.asarray(oth), jnp.asarray(hi),
-            jnp.asarray(lo), block=256)
+            jnp.asarray(lo), block=1024)
         live = (seg != oth) & ~((hi == inf) & (lo == inf))
         mh = np.where(live, hi, inf).astype(np.uint32)
         ml = np.where(live, lo, inf).astype(np.uint32)
@@ -380,20 +373,31 @@ def test_masked_minplus_scan_matches_masked_oracle():
     assert np.array_equal(np.asarray(gl), np.asarray(wl))
 
 
-def test_shortcut_relabel_kernel_matches_ref():
-    from repro.kernels.spmv_minplus import ops, ref
-    rng = np.random.default_rng(11)
-    for n in (1, 2, 97, 1024):
-        # hook_min-shaped forests: parent[i] <= i.
-        parent = np.minimum(rng.integers(0, n, n), np.arange(n)).astype(
-            np.uint32)
-        comp = rng.integers(0, n, n).astype(np.uint32)
-        want = ref.shortcut_relabel(jnp.asarray(parent), jnp.asarray(comp))
-        got = ops.shortcut_relabel(jnp.asarray(parent), jnp.asarray(comp),
-                                   use_pallas=True)
-        assert np.array_equal(np.asarray(got), np.asarray(want)), n
-        # Fully compressed: every label points at its root.
-        root = parent.copy()
-        for _ in range(max(int(np.ceil(np.log2(max(n, 2)))), 1)):
-            root = root[root]
-        assert np.array_equal(np.asarray(got), root[comp]), n
+@pytest.mark.parametrize("runs", ["one_run", "row_edges", "singletons",
+                                  "block_edges"])
+@pytest.mark.parametrize("block", [1024, 2048])
+def test_scan_kernels_tile_boundaries(runs, block):
+    """Segments that end on, or straddle, a lane row (128), a sublane
+    tile and a grid block: the row/lane shifts and the cross-block carry
+    of the 2-D tiled scan must match the sequential oracles exactly."""
+    from repro.kernels.segment_min import ref
+    from repro.kernels.segment_min.segment_min import (
+        segmented_min2_scan, segmented_min_scan)
+    m = 4 * block
+    idx = np.arange(m)
+    seg = {"one_run": np.zeros(m),
+           "row_edges": idx // 127 + (idx % 128 == 127),
+           "singletons": idx,
+           "block_edges": (idx + 1) // (block + 1)}[runs]
+    seg = np.maximum.accumulate(seg).astype(np.int32)
+    hi = RNG.integers(0, 30, m, dtype=np.uint32)
+    lo = RNG.integers(0, 2**32 - 2, m, dtype=np.uint32)
+    got = segmented_min_scan(jnp.asarray(seg), jnp.asarray(lo), block=block)
+    want = ref.segmented_min_scan(jnp.asarray(seg), jnp.asarray(lo))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    gh, gl = segmented_min2_scan(jnp.asarray(seg), jnp.asarray(hi),
+                                 jnp.asarray(lo), block=block)
+    wh, wl = ref.segmented_min2_scan(jnp.asarray(seg), jnp.asarray(hi),
+                                     jnp.asarray(lo))
+    assert np.array_equal(np.asarray(gh), np.asarray(wh))
+    assert np.array_equal(np.asarray(gl), np.asarray(wl))

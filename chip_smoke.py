@@ -4,13 +4,13 @@ to end, on one TPU chip (or on four with ``--chips 4``).
 
 Run from the root of a checkout, as one process that starts no other:
 
-    python3 chip_smoke.py               # one chip, rmat scale 20
+    python3 chip_smoke.py               # one chip, rmat scale 21
     python3 chip_smoke.py --chips 4     # the four-chip mesh phase only
 
 One chip runs six phases through the public entry points
 (``repro.core.mst_api`` and ``repro.launch.serve.MSTService``) on a Graph500
 Kronecker graph built on the device (R-MAT a=.57 b=.19 c=.19, edge factor
-16; scale 20 by default, 2**20 vertices and 2**24 sampled edges — see
+16; scale 21 by default, 2**21 vertices and 2**25 sampled edges — see
 ``WHY_SCALE``):
 
 1. ``boruvka``        Borůvka with default params (XLA round body);
@@ -45,14 +45,17 @@ import threading
 import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-SCALE = 20
+SCALE = 21
 WHY_SCALE = (
-    "a cold scale-22 run (2**22 vertices, 2**26 edge slots) on one v5e "
-    "took over 1300 s: each Borůvka round at 2**26 edges takes tens of "
-    "seconds there (see PERF.md), so the six phases do not "
-    "fit the smoke's 1200 s; memory was not the limit (its largest "
-    "program, the build, compiles to 8.4 GiB of 16 GB)")
+    "time, not memory. A cold scale-22 run on one v5e was cut at 1300 s "
+    "inside phase 6 (phases 1-5 took 1,180 s of wall time, though "
+    "phase 1's 478 s also held the wait for the host oracle). Scaling the "
+    "cold scale-20 phase times by the growth measured from scale 20 to 22 "
+    "(4.0x for the Pallas round) projects about 1,220-1,240 s for scale "
+    "22, past the 1200 s limit. Memory fits: the largest scale-22 "
+    "program, the build, compiles to 8.4 GiB of 16 GB")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPRESSED_CHECK = 3    # rounds per interval of the 4-chip compressed solve
 
 
 class Background:
@@ -158,11 +161,13 @@ def forest_oracle(spec):
 
 def update_oracle(first, seed):
     """The phase-6 batch, drawn from the reference forest, and the
-    reference forest of the updated graph."""
+    reference forest of the updated graph.  The updated graph comes from
+    the preprocess-based definition, not the sorted merge that
+    ``apply_updates`` runs."""
     from repro.core import incremental, kruskal_ref
     g, f = first.result()
     batch = update_batch(g, f.edge_mask, seed)
-    g2 = incremental.apply_edge_batch(g, batch)
+    g2 = incremental._apply_edge_batch_reference(g, batch)
     return batch, g2, kruskal_ref.boruvka_numpy(g2)
 
 
@@ -222,7 +227,8 @@ def one_chip(args, meter):
         # cached jit object), lowered again from its first call's shapes.
         n, m, cap = edges.num_vertices, edges.num_edges, edges.capacity
         sds = jax.ShapeDtypeStruct
-        fn = boruvka_dist._build_interval_fn_fused(None, "pallas", None)
+        fn = boruvka_dist._build_interval_fn_fused(None, "pallas", None,
+                                                    "pmin")
         with jax.enable_x64():
             text = fn.lower(
                 sds((n,), jnp.uint32), sds((m,), jnp.bool_),
@@ -335,16 +341,34 @@ def four_chips(args, meter):
             shard_len=shards[0].data.shape[0])
 
     meter.run("build_4chip", build)
-    for collective in ("pmin", "compressed"):
-        params = dataclasses.replace(DEFAULT_PARAMS, collective=collective)
+    # Under "compressed", intervals of COMPRESSED_CHECK rounds give the
+    # host a fragment census early enough that later intervals pick the
+    # ppermute ring over dense pmin (DESIGN.md §11 byte model).
+    for collective, every in (("pmin", DEFAULT_PARAMS.check_frequency),
+                              ("compressed", COMPRESSED_CHECK)):
+        params = dataclasses.replace(DEFAULT_PARAMS, collective=collective,
+                                     check_frequency=every)
 
         def solve():
             res, st = mst_api.minimum_spanning_forest(
                 box["edges"], method="boruvka", params=params, mesh=mesh)
-            return lambda: (np.array_equal(
-                res.edge_mask, oracle.result()[1].edge_mask), {}), dict(
-                collective=collective, rounds=st.rounds,
+            ring = sum(r for mode, _, r, _ in st.comm_history
+                       if mode == "compressed")
+            box[collective] = st.comm_bytes
+
+            def check():
+                exact = np.array_equal(res.edge_mask,
+                                       oracle.result()[1].edge_mask)
+                if collective == "pmin":
+                    return exact, {}
+                ran = ring > 0 and st.comm_bytes < box["pmin"]
+                return exact and ran, dict(ring_ran=ran)
+            return check, dict(
+                collective=collective, check_frequency=every,
+                rounds=st.rounds, ring_rounds=ring,
                 intervals=st.intervals, comm_bytes=st.comm_bytes,
+                comm_history=",".join(f"{m}:{c}:{r}"
+                                      for m, c, r, _ in st.comm_history),
                 staging=st.edge_staging)
 
         meter.run(f"boruvka_4chip_{collective}", solve)

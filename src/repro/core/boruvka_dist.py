@@ -503,7 +503,10 @@ def _device_engine(
         fused = runtime.resolve_round_kernel(params.round_kernel) == "pallas"
         # fn_pmin: full-width pmin rounds.  Under collective="compressed"
         # it is the dense fallback, and still takes the candidate census
-        # the compressed executables' caps are derived from.
+        # the compressed executables' caps are derived from.  Off a mesh
+        # there is no collective, so one executable (keyed "pmin") serves
+        # both settings.
+        fn_coll = collective if mesh is not None else "pmin"
         if fused:
             # Fused round body (DESIGN.md §9): replicated canonical-eid
             # bitmap + replicated canonical endpoints for n-scale winner
@@ -523,11 +526,11 @@ def _device_engine(
             lowering = ("pallas" if params.use_pallas
                         else "sort" if sort_bits is not None else "scatter")
             sb = sort_bits if lowering == "sort" else None
-            fn_pmin = _build_interval_fn_fused(mesh, lowering, sb, collective)
+            fn_pmin = _build_interval_fn_fused(mesh, lowering, sb, fn_coll)
         else:
             lowering = sb = None
             mask_dev = put(np.zeros(m0, dtype=bool), edge_sh)
-            fn_pmin = _build_interval_fn(mesh, params.use_pallas, collective)
+            fn_pmin = _build_interval_fn(mesh, params.use_pallas, fn_coll)
 
         overlap = (runtime.resolve_interval_pipeline(
             params.interval_pipeline) == 1)

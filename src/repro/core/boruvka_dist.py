@@ -41,6 +41,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
@@ -112,9 +113,6 @@ class BoruvkaStats(runtime.EngineStats):
     rounds: int = 0
     compactions: int = 0
     edges_scanned: int = 0          # Σ active (padded) edges per round
-    active_history: tuple = ()      # host loop: global active edges per round;
-                                    # device loop: MAX per-shard active count
-                                    # per interval (the compaction-cap census)
     comm_history: tuple = ()        # device loop: one (mode, cand_cap,
                                     # rounds, bytes) record per consumed
                                     # interval — per-shard on-wire bytes of
@@ -145,35 +143,44 @@ def _one_round(
     (n,)/(cap,) state and the batched engine maps it over a leading batch
     axis with ``jax.vmap`` — both run the exact same ops, which is what
     keeps batched lanes bit-identical to single-graph solves.
+
+    The phases run under the ``jax.named_scope`` names ``election``,
+    ``record`` and ``merge``, which a profile's device ops carry in their
+    op name (the interval's censuses and compaction add ``census`` and
+    ``compact``).
     """
+    from repro.kernels.segment_min import ops as segops
     n = comp.shape[0]
     cap = mask.shape[0]
-    cs = comp[src]          # PAD_VERTEX clamps → padding is a self-loop
-    cd = comp[dst]
-    alive = (cs != cd) & (key != INF_KEY)
-    k = jnp.where(alive, key, INF_KEY)
-    # Fused MOE election: ONE segmented min over both endpoints, ONE
-    # collective.  The packed key carries the tie-break, so no second
-    # (weight-match, edge-id) pass and no second pmin.
-    seg = jnp.concatenate([cs, cd]).astype(jnp.int32)
-    from repro.kernels.segment_min import ops as segops
-    best = segops.segment_min64(
-        jnp.concatenate([k, k]), seg, num_segments=n,
-        use_pallas=use_pallas)
-    best = pmin(best, INF_KEY)
-    winners = alive & ((best[cs] == k) | (best[cd] == k))
-    # Record wins into the sharded bitmap; an edge's bitmap slot lives on
-    # the shard that loaded it (compaction is shard-local), so the
-    # scatter is local for every partitioner.
-    mask = mask.at[jnp.where(winners, slot, cap)].set(True, mode="drop")
-    # Merge: min-hooking + pointer doubling (GHS Connect/Initiate).
-    hi = jnp.maximum(cs, cd).astype(jnp.uint32)
-    lo = jnp.minimum(cs, cd).astype(jnp.uint32)
-    parent = union_find.hook_min(n, hi, lo, winners)
-    parent = pmin(parent, jnp.arange(n, dtype=jnp.uint32))
-    parent = union_find.pointer_double(parent)
-    done = jnp.all(best == INF_KEY)
-    return parent[comp], mask, done
+    with jax.named_scope("election"):
+        cs = comp[src]      # PAD_VERTEX clamps → padding is a self-loop
+        cd = comp[dst]
+        alive = (cs != cd) & (key != INF_KEY)
+        k = jnp.where(alive, key, INF_KEY)
+        # Fused MOE election: ONE segmented min over both endpoints, ONE
+        # collective.  The packed key carries the tie-break, so no second
+        # (weight-match, edge-id) pass and no second pmin.
+        seg = jnp.concatenate([cs, cd]).astype(jnp.int32)
+        best = segops.segment_min64(
+            jnp.concatenate([k, k]), seg, num_segments=n,
+            use_pallas=use_pallas)
+        best = pmin(best, INF_KEY)
+        winners = alive & ((best[cs] == k) | (best[cd] == k))
+        done = jnp.all(best == INF_KEY)
+    with jax.named_scope("record"):
+        # Record wins into the sharded bitmap; an edge's bitmap slot lives
+        # on the shard that loaded it (compaction is shard-local), so the
+        # scatter is local for every partitioner.
+        mask = mask.at[jnp.where(winners, slot, cap)].set(True, mode="drop")
+    with jax.named_scope("merge"):
+        # Min-hooking + pointer doubling (GHS Connect/Initiate).
+        hi = jnp.maximum(cs, cd).astype(jnp.uint32)
+        lo = jnp.minimum(cs, cd).astype(jnp.uint32)
+        parent = union_find.hook_min(n, hi, lo, winners)
+        parent = pmin(parent, jnp.arange(n, dtype=jnp.uint32))
+        parent = union_find.pointer_double(parent)
+        comp = parent[comp]
+    return comp, mask, done
 
 
 def _censuses(comp, src, dst, key, axis_name: Optional[str],
@@ -190,22 +197,23 @@ def _censuses(comp, src, dst, key, axis_name: Optional[str],
     it is taken only where a compressed exchange can read it (a mesh under
     ``collective="compressed"``); elsewhere the trivial bound ``n`` stands.
     """
-    active = (comp[src] != comp[dst]) & (key != INF_KEY)
-    n_active = active.sum(dtype=jnp.int32)
-    n = comp.shape[0]
-    if not axis_name:
-        return n_active, jnp.int32(n)
-    if collective == "compressed":
-        seg = jnp.concatenate([comp[src], comp[dst]]).astype(jnp.uint32)
-        seg = jnp.where(jnp.concatenate([active, active]), seg,
-                        jnp.uint32(n))
-        (seg,) = jax.lax.sort((seg,), num_keys=1)
-        first = (seg != jnp.uint32(n)) & jnp.concatenate(
-            [jnp.ones((1,), bool), seg[1:] != seg[:-1]])
-        n_cand = jax.lax.pmax(first.sum(dtype=jnp.int32), axis_name)
-    else:
-        n_cand = jnp.int32(n)
-    return jax.lax.pmax(n_active, axis_name), n_cand
+    with jax.named_scope("census"):
+        active = (comp[src] != comp[dst]) & (key != INF_KEY)
+        n_active = active.sum(dtype=jnp.int32)
+        n = comp.shape[0]
+        if not axis_name:
+            return n_active, jnp.int32(n)
+        if collective == "compressed":
+            seg = jnp.concatenate([comp[src], comp[dst]]).astype(jnp.uint32)
+            seg = jnp.where(jnp.concatenate([active, active]), seg,
+                            jnp.uint32(n))
+            (seg,) = jax.lax.sort((seg,), num_keys=1)
+            first = (seg != jnp.uint32(n)) & jnp.concatenate(
+                [jnp.ones((1,), bool), seg[1:] != seg[:-1]])
+            n_cand = jax.lax.pmax(first.sum(dtype=jnp.int32), axis_name)
+        else:
+            n_cand = jnp.int32(n)
+        return jax.lax.pmax(n_active, axis_name), n_cand
 
 
 def _run_interval(
@@ -302,26 +310,29 @@ def _one_round_fused(
     from repro.kernels.spmv_minplus import ops as spmv_ops
     n = comp.shape[0]
     m = mask.shape[0]
-    cs = comp[src]          # PAD_VERTEX clamps → padding is a self-loop
-    cd = comp[dst]
-    best = spmv_ops.elect(cs, cd, key, num_segments=n, lowering=lowering,
-                          sort_bits=sort_bits)
-    best = pmin(best, INF_KEY)
-    elected = best != INF_KEY
-    eid = keys_lib.unpack_edge_id(best)      # 0xFFFFFFFF when not elected
-    mask = mask.at[jnp.where(elected, eid, jnp.uint32(m))].set(
-        True, mode="drop")
-    u = csrc[eid]           # clamped gathers; garbage gated by ``elected``
-    v = cdst[eid]
-    cu = comp[u]
-    cv = comp[v]
-    f = jnp.arange(n, dtype=jnp.uint32)
-    other = jnp.where(cu == f, cv, cu)
-    hi = jnp.maximum(f, other)
-    lo = jnp.minimum(f, other)
-    parent = union_find.hook_min(n, hi, lo, elected)
-    comp = spmv_ops.shortcut_relabel(parent, comp)
-    done = jnp.all(best == INF_KEY)
+    with jax.named_scope("election"):
+        cs = comp[src]      # PAD_VERTEX clamps → padding is a self-loop
+        cd = comp[dst]
+        best = spmv_ops.elect(cs, cd, key, num_segments=n,
+                              lowering=lowering, sort_bits=sort_bits)
+        best = pmin(best, INF_KEY)
+        elected = best != INF_KEY
+        eid = keys_lib.unpack_edge_id(best)  # 0xFFFFFFFF when not elected
+        done = jnp.all(best == INF_KEY)
+    with jax.named_scope("record"):
+        mask = mask.at[jnp.where(elected, eid, jnp.uint32(m))].set(
+            True, mode="drop")
+    with jax.named_scope("merge"):
+        u = csrc[eid]       # clamped gathers; garbage gated by ``elected``
+        v = cdst[eid]
+        cu = comp[u]
+        cv = comp[v]
+        f = jnp.arange(n, dtype=jnp.uint32)
+        other = jnp.where(cu == f, cv, cu)
+        hi = jnp.maximum(f, other)
+        lo = jnp.minimum(f, other)
+        parent = union_find.hook_min(n, hi, lo, elected)
+        comp = spmv_ops.shortcut_relabel(parent, comp)
     return comp, mask, done
 
 
@@ -414,17 +425,18 @@ def _compact_shard(comp, src, dst, key, slot, *, cap: int):
     ``cap`` is static (a power-of-two bucket), so shapes stay rectangular
     across shards.
     """
-    keep = (comp[src] != comp[dst]) & (key != INF_KEY)
-    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    idx = jnp.where(keep, pos, cap)
-    new_src = jnp.full((cap,), PAD_VERTEX, jnp.int32).at[idx].set(
-        src, mode="drop")
-    new_dst = jnp.full((cap,), PAD_VERTEX, jnp.int32).at[idx].set(
-        dst, mode="drop")
-    new_key = jnp.full((cap,), INF_KEY, jnp.uint64).at[idx].set(
-        key, mode="drop")
-    new_slot = jnp.full((cap,), _PAD_SLOT, jnp.int32).at[idx].set(
-        slot, mode="drop")
+    with jax.named_scope("compact"):
+        keep = (comp[src] != comp[dst]) & (key != INF_KEY)
+        pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+        idx = jnp.where(keep, pos, cap)
+        new_src = jnp.full((cap,), PAD_VERTEX, jnp.int32).at[idx].set(
+            src, mode="drop")
+        new_dst = jnp.full((cap,), PAD_VERTEX, jnp.int32).at[idx].set(
+            dst, mode="drop")
+        new_key = jnp.full((cap,), INF_KEY, jnp.uint64).at[idx].set(
+            key, mode="drop")
+        new_slot = jnp.full((cap,), _PAD_SLOT, jnp.int32).at[idx].set(
+            slot, mode="drop")
     return new_src, new_dst, new_key, new_slot
 
 
@@ -482,25 +494,39 @@ def _device_engine(
         if np.any(source.weight.view(np.uint32) == INF32):
             raise ValueError("weights collide with the INF sentinel")
 
+    collective = runtime.resolve_collective(params.collective)
+    fused = runtime.resolve_round_kernel(params.round_kernel) == "pallas"
+    edge_sh = NamedSharding(mesh, P(_AXIS)) if mesh is not None else None
+    repl_sh = NamedSharding(mesh, P()) if mesh is not None else None
+
+    def put(a, sh):
+        return jax.device_put(a, sh) if sh is not None else jnp.asarray(a)
+
     with jax.enable_x64():
-        bundle = runtime.prepare_edges(
-            source, params.partitioner, mesh, chunk=chunk)
-        n, m = bundle.num_vertices, bundle.num_edges
+        with TraceAnnotation("msf.stage"):
+            bundle = runtime.prepare_edges(
+                source, params.partitioner, mesh, chunk=chunk)
+            n, m = bundle.num_vertices, bundle.num_edges
+            comp_dev = put(np.arange(n, dtype=np.uint32), repl_sh)
+            if fused:
+                # Fused round body (DESIGN.md §9): replicated canonical-eid
+                # bitmap + replicated canonical endpoints for n-scale
+                # winner recording and hooking.  bundle.graph() is the
+                # same host mirror forest_from_mask reads at the end, so
+                # this stages no transfer the solve would not have made
+                # anyway.
+                g_host = bundle.graph()
+                pad = np.zeros(1, np.int32)
+                csrc_d = put(g_host.src if m else pad, repl_sh)
+                cdst_d = put(g_host.dst if m else pad, repl_sh)
+                mask_dev = put(np.zeros(m, dtype=bool), repl_sh)
+            else:
+                mask_dev = put(np.zeros(bundle.layout.num_slots, dtype=bool),
+                               edge_sh)
         layout = bundle.layout
-        m0 = layout.num_slots
-
-        edge_sh = NamedSharding(mesh, P(_AXIS)) if mesh is not None else None
-        repl_sh = NamedSharding(mesh, P()) if mesh is not None else None
-
-        def put(a, sh):
-            return jax.device_put(a, sh) if sh is not None else jnp.asarray(a)
-
         src_d, dst_d, key_d, slot_d = (bundle.src, bundle.dst, bundle.key,
                                        bundle.slot)
-        comp_dev = put(np.arange(n, dtype=np.uint32), repl_sh)
 
-        collective = runtime.resolve_collective(params.collective)
-        fused = runtime.resolve_round_kernel(params.round_kernel) == "pallas"
         # fn_pmin: full-width pmin rounds.  Under collective="compressed"
         # it is the dense fallback, and still takes the candidate census
         # the compressed executables' caps are derived from.  Off a mesh
@@ -508,16 +534,7 @@ def _device_engine(
         # both settings.
         fn_coll = collective if mesh is not None else "pmin"
         if fused:
-            # Fused round body (DESIGN.md §9): replicated canonical-eid
-            # bitmap + replicated canonical endpoints for n-scale winner
-            # recording and hooking.  bundle.graph() is the same host
-            # mirror forest_from_mask reads at the end, so this stages no
-            # transfer the solve would not have made anyway.
             from repro.kernels.spmv_minplus import ops as spmv_ops
-            g_host = bundle.graph()
-            csrc_d = put(g_host.src if m else np.zeros(1, np.int32), repl_sh)
-            cdst_d = put(g_host.dst if m else np.zeros(1, np.int32), repl_sh)
-            mask_dev = put(np.zeros(m, dtype=bool), repl_sh)
             sort_bits = spmv_ops.sort_gate(n, m)
             if sort_bits is not None and np.any(
                     g_host.weight.view(np.uint32)
@@ -529,7 +546,6 @@ def _device_engine(
             fn_pmin = _build_interval_fn_fused(mesh, lowering, sb, fn_coll)
         else:
             lowering = sb = None
-            mask_dev = put(np.zeros(m0, dtype=bool), edge_sh)
             fn_pmin = _build_interval_fn(mesh, params.use_pallas, fn_coll)
 
         overlap = (runtime.resolve_interval_pipeline(
@@ -538,7 +554,6 @@ def _device_engine(
         cap_rounds = max_rounds or (n + 2)
         stats = BoruvkaStats()
         stats.edge_staging = bundle.staging
-        history = []
         comm_hist = []
         # Value lanes of the per-round reductions, for the §11 wire model:
         # xla rounds exchange best (uint64) + hook parents (uint32); fused
@@ -609,17 +624,17 @@ def _device_engine(
             stats.edges_scanned += r * blk * num_shards
             stats.comm_bytes += r * bytes_per_round
             comm_hist.append((mode, cand_cap, r, r * bytes_per_round))
-            history.append(int(n_act))
             box["cand_bound"] = max(min(n, int(n_cand)), 1)
             if bool(done_v):
                 return s, True
             if params.compaction == "pow2":
                 new_block = max(_pow2ceil(int(n_act)), 8)
                 if new_block < box["cur_block"]:   # shrink: ≤ log2 recompiles
-                    cfn = _build_compact_fn(mesh, new_block)
                     comp_dev, mask_dev, src_d, dst_d, key_d, slot_d = s
-                    src_d, dst_d, key_d, slot_d = cfn(
-                        comp_dev, src_d, dst_d, key_d, slot_d)
+                    with TraceAnnotation("msf.compact"):
+                        cfn = _build_compact_fn(mesh, new_block)
+                        src_d, dst_d, key_d, slot_d = cfn(
+                            comp_dev, src_d, dst_d, key_d, slot_d)
                     s = (comp_dev, mask_dev, src_d, dst_d, key_d, slot_d)
                     box["cur_block"] = new_block
                     stats.compactions += 1
@@ -631,22 +646,24 @@ def _device_engine(
             fail_msg="Borůvka engine failed to converge",
             overlap=overlap)[:2]
 
-        comp_final, mask_full = jax.device_get((comp_dev, mask_dev))
+        with TraceAnnotation("msf.fetch"):
+            comp_final, mask_full = jax.device_get((comp_dev, mask_dev))
         stats.host_syncs += 1          # final state fetch
         stats.extra_syncs += 1
 
-    comp_final = np.asarray(comp_final)
-    if fused:
-        # The fused rounds record winners canonical-eid-indexed directly.
-        mask = np.asarray(mask_full)
-    else:
-        # The bitmap lives in the load-time slot layout; the layout maps
-        # slots back to canonical edge ids (padding slots never set).
-        mask = layout.canonical_mask(np.asarray(mask_full), m)
-    ncomp = int(np.unique(comp_final).size)
-    res = runtime.forest_from_mask(bundle.graph(), mask, num_components=ncomp)
-    res.check_consistent(n)
-    stats.active_history = tuple(history)
+    with TraceAnnotation("msf.forest"):
+        comp_final = np.asarray(comp_final)
+        if fused:
+            # The fused rounds record winners canonical-eid-indexed directly.
+            mask = np.asarray(mask_full)
+        else:
+            # The bitmap lives in the load-time slot layout; the layout maps
+            # slots back to canonical edge ids (padding slots never set).
+            mask = layout.canonical_mask(np.asarray(mask_full), m)
+        ncomp = int(np.unique(comp_final).size)
+        res = runtime.forest_from_mask(bundle.graph(), mask,
+                                       num_components=ncomp)
+        res.check_consistent(n)
     stats.comm_history = tuple(comm_hist)
     return res, stats
 
@@ -677,7 +694,6 @@ class BatchStats(BoruvkaStats):
         self.rounds += st.rounds
         self.compactions += st.compactions
         self.edges_scanned += st.edges_scanned
-        self.active_history += st.active_history
         self.overlapped_syncs += st.overlapped_syncs
         self.speculative_intervals += st.speculative_intervals
         self.comm_bytes += st.comm_bytes
@@ -701,55 +717,62 @@ def _one_round_packed(comp, mask, src, dst, key, slot, *,
     n = comp.shape[0]
     cap = mask.shape[0]
     ones = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-    cs = comp[src]          # PAD_VERTEX clamps → padding is a self-loop
-    cd = comp[dst]
-    alive = (cs != cd) & (key != INF_KEY)
-    wbits = key >> jnp.uint64(32)
-    eid = key & jnp.uint64(0xFFFFFFFF)
-    base = ((wbits << jnp.uint64(c_bits + s_bits))
-            | (eid << jnp.uint64(s_bits)))
-    if election == "sort":
-        # round_kernel="pallas", batched: the same masked min-plus election
-        # lowered scatter-free — prepend the electing fragment ABOVE the
-        # packed value (weights < 1.0 keep wbits in 30 bits, so
-        # (seg ‖ wbits ‖ eid ‖ other) is 2·s_bits + 30 + c_bits ≤ 64, the
-        # contraction gate), key-only sort, and read each fragment's
-        # winner back with a searchsorted probe.  Exact min over identical
-        # values → bit-identical to the scatter election.
-        shift = jnp.uint64(30 + c_bits + s_bits)
-        sk = jnp.concatenate([
-            jnp.where(alive, (cs.astype(jnp.uint64) << shift)
-                      | base | cd.astype(jnp.uint64), ones),
-            jnp.where(alive, (cd.astype(jnp.uint64) << shift)
-                      | base | cs.astype(jnp.uint64), ones),
-        ])
-        (sk,) = jax.lax.sort((sk,), num_keys=1)
-        m2 = sk.shape[0]
-        frag = jnp.arange(n, dtype=jnp.uint64)
-        pos = jnp.searchsorted(sk, frag << shift)
-        cand = sk[jnp.minimum(pos, m2 - 1)]
-        found = (pos < m2) & ((cand >> shift) == frag) & (cand != ones)
-        best = jnp.where(
-            found, cand & ((jnp.uint64(1) << shift) - jnp.uint64(1)), ones)
-    else:
-        seg = jnp.concatenate([cs, cd]).astype(jnp.int32)
-        val = jnp.concatenate([
-            jnp.where(alive, base | cd.astype(jnp.uint64), ones),
-            jnp.where(alive, base | cs.astype(jnp.uint64), ones),
-        ])
-        best = jnp.full((n,), ones, jnp.uint64).at[seg].min(val, mode="drop")
-    elected = best != ones
-    best_eid = ((best >> jnp.uint64(s_bits))
-                & jnp.uint64((1 << c_bits) - 1)).astype(jnp.int32)
-    other = (best & jnp.uint64((1 << s_bits) - 1)).astype(jnp.uint32)
-    mask = mask.at[jnp.where(elected, best_eid, cap)].set(True, mode="drop")
-    f = jnp.arange(n, dtype=jnp.uint32)
-    hi = jnp.maximum(f, other)
-    lo = jnp.minimum(f, other)
-    parent = union_find.hook_min(n, hi, lo, elected)
-    parent = union_find.pointer_double(parent)
-    done = jnp.all(best == ones)
-    return parent[comp], mask, done
+    with jax.named_scope("election"):
+        cs = comp[src]      # PAD_VERTEX clamps → padding is a self-loop
+        cd = comp[dst]
+        alive = (cs != cd) & (key != INF_KEY)
+        wbits = key >> jnp.uint64(32)
+        eid = key & jnp.uint64(0xFFFFFFFF)
+        base = ((wbits << jnp.uint64(c_bits + s_bits))
+                | (eid << jnp.uint64(s_bits)))
+        if election == "sort":
+            # round_kernel="pallas", batched: the same masked min-plus
+            # election lowered scatter-free — prepend the electing fragment
+            # ABOVE the packed value (weights < 1.0 keep wbits in 30 bits,
+            # so (seg ‖ wbits ‖ eid ‖ other) is 2·s_bits + 30 + c_bits ≤ 64,
+            # the contraction gate), key-only sort, and read each
+            # fragment's winner back with a searchsorted probe.  Exact min
+            # over identical values → bit-identical to the scatter
+            # election.
+            shift = jnp.uint64(30 + c_bits + s_bits)
+            sk = jnp.concatenate([
+                jnp.where(alive, (cs.astype(jnp.uint64) << shift)
+                          | base | cd.astype(jnp.uint64), ones),
+                jnp.where(alive, (cd.astype(jnp.uint64) << shift)
+                          | base | cs.astype(jnp.uint64), ones),
+            ])
+            (sk,) = jax.lax.sort((sk,), num_keys=1)
+            m2 = sk.shape[0]
+            frag = jnp.arange(n, dtype=jnp.uint64)
+            pos = jnp.searchsorted(sk, frag << shift)
+            cand = sk[jnp.minimum(pos, m2 - 1)]
+            found = (pos < m2) & ((cand >> shift) == frag) & (cand != ones)
+            low = (jnp.uint64(1) << shift) - jnp.uint64(1)
+            best = jnp.where(found, cand & low, ones)
+        else:
+            seg = jnp.concatenate([cs, cd]).astype(jnp.int32)
+            val = jnp.concatenate([
+                jnp.where(alive, base | cd.astype(jnp.uint64), ones),
+                jnp.where(alive, base | cs.astype(jnp.uint64), ones),
+            ])
+            best = jnp.full((n,), ones, jnp.uint64).at[seg].min(
+                val, mode="drop")
+        elected = best != ones
+        best_eid = ((best >> jnp.uint64(s_bits))
+                    & jnp.uint64((1 << c_bits) - 1)).astype(jnp.int32)
+        other = (best & jnp.uint64((1 << s_bits) - 1)).astype(jnp.uint32)
+        done = jnp.all(best == ones)
+    with jax.named_scope("record"):
+        mask = mask.at[jnp.where(elected, best_eid, cap)].set(
+            True, mode="drop")
+    with jax.named_scope("merge"):
+        f = jnp.arange(n, dtype=jnp.uint32)
+        hi = jnp.maximum(f, other)
+        lo = jnp.minimum(f, other)
+        parent = union_find.hook_min(n, hi, lo, elected)
+        parent = union_find.pointer_double(parent)
+        comp = parent[comp]
+    return comp, mask, done
 
 
 def _contract_lane(comp, src, dst, key, *, s_bits: int, c_bits: int):
@@ -771,35 +794,37 @@ def _contract_lane(comp, src, dst, key, *, s_bits: int, c_bits: int):
     (DESIGN.md §7), instead of the per-element scatters that dominate the
     round loop at serving scales.  Every field unpacks from the sorted key.
     """
-    ones = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-    cu = comp[src]          # PAD_VERTEX clamps → padding stays a self-loop
-    cd = comp[dst]
-    alive = (cu != cd) & (key != INF_KEY)
-    lo = jnp.minimum(cu, cd).astype(jnp.uint64)
-    hi = jnp.maximum(cu, cd).astype(jnp.uint64)
-    wbits = key >> jnp.uint64(32)
-    eid = key & jnp.uint64(0xFFFFFFFF)
-    packed = ((lo << jnp.uint64(c_bits + 30 + s_bits))
-              | (hi << jnp.uint64(c_bits + 30))
-              | (wbits << jnp.uint64(c_bits))
-              | eid)
-    packed = jnp.where(alive, packed, ones)
-    (packed,) = jax.lax.sort((packed,), num_keys=1)
-    pair = packed >> jnp.uint64(c_bits + 30)
-    valid = packed != ones
-    first = valid & jnp.concatenate(
-        [jnp.ones((1,), bool), pair[1:] != pair[:-1]])
-    count = first.sum(dtype=jnp.int32)
-    (kept,) = jax.lax.sort((jnp.where(first, packed, ones),), num_keys=1)
-    dead = kept == ones
-    eid2 = kept & jnp.uint64((1 << c_bits) - 1)
-    wb2 = (kept >> jnp.uint64(c_bits)) & jnp.uint64((1 << 30) - 1)
-    hi2 = (kept >> jnp.uint64(c_bits + 30)) & jnp.uint64((1 << s_bits) - 1)
-    lo2 = kept >> jnp.uint64(c_bits + 30 + s_bits)
-    new_src = jnp.where(dead, PAD_VERTEX, lo2.astype(jnp.int32))
-    new_dst = jnp.where(dead, PAD_VERTEX, hi2.astype(jnp.int32))
-    new_key = jnp.where(dead, INF_KEY, (wb2 << jnp.uint64(32)) | eid2)
-    new_slot = jnp.where(dead, _PAD_SLOT, eid2.astype(jnp.int32))
+    with jax.named_scope("compact"):
+        ones = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+        cu = comp[src]      # PAD_VERTEX clamps → padding stays a self-loop
+        cd = comp[dst]
+        alive = (cu != cd) & (key != INF_KEY)
+        lo = jnp.minimum(cu, cd).astype(jnp.uint64)
+        hi = jnp.maximum(cu, cd).astype(jnp.uint64)
+        wbits = key >> jnp.uint64(32)
+        eid = key & jnp.uint64(0xFFFFFFFF)
+        packed = ((lo << jnp.uint64(c_bits + 30 + s_bits))
+                  | (hi << jnp.uint64(c_bits + 30))
+                  | (wbits << jnp.uint64(c_bits))
+                  | eid)
+        packed = jnp.where(alive, packed, ones)
+        (packed,) = jax.lax.sort((packed,), num_keys=1)
+        pair = packed >> jnp.uint64(c_bits + 30)
+        valid = packed != ones
+        first = valid & jnp.concatenate(
+            [jnp.ones((1,), bool), pair[1:] != pair[:-1]])
+        count = first.sum(dtype=jnp.int32)
+        (kept,) = jax.lax.sort((jnp.where(first, packed, ones),), num_keys=1)
+        dead = kept == ones
+        eid2 = kept & jnp.uint64((1 << c_bits) - 1)
+        wb2 = (kept >> jnp.uint64(c_bits)) & jnp.uint64((1 << 30) - 1)
+        hi2 = ((kept >> jnp.uint64(c_bits + 30))
+               & jnp.uint64((1 << s_bits) - 1))
+        lo2 = kept >> jnp.uint64(c_bits + 30 + s_bits)
+        new_src = jnp.where(dead, PAD_VERTEX, lo2.astype(jnp.int32))
+        new_dst = jnp.where(dead, PAD_VERTEX, hi2.astype(jnp.int32))
+        new_key = jnp.where(dead, INF_KEY, (wb2 << jnp.uint64(32)) | eid2)
+        new_slot = jnp.where(dead, _PAD_SLOT, eid2.astype(jnp.int32))
     return new_src, new_dst, new_key, new_slot, count
 
 
@@ -868,9 +893,10 @@ def _run_interval_batch(
         census = counts.max()
     else:
         # Active-edge census (max over lanes) for the compaction cap.
-        census = jax.vmap(
-            lambda c, s, d, k: ((c[s] != c[d]) & (k != INF_KEY)).sum(
-                dtype=jnp.int32))(comp, src, dst, key).max()
+        with jax.named_scope("census"):
+            census = jax.vmap(
+                lambda c, s, d, k: ((c[s] != c[d]) & (k != INF_KEY)).sum(
+                    dtype=jnp.int32))(comp, src, dst, key).max()
     return (comp, mask, src, dst, key, slot, done, rdone,
             jnp.all(done), r, census)
 
@@ -898,8 +924,10 @@ def _build_batch_interval_fn(
 def _build_batch_shrink_fn(cap: int) -> Callable:
     """Slice every lane's (contracted, front-packed) edge arrays down to
     ``cap`` slots — a static-shape copy, no readback needed."""
-    return jax.jit(lambda src, dst, key, slot: (
-        src[:, :cap], dst[:, :cap], key[:, :cap], slot[:, :cap]))
+    def shrink(src, dst, key, slot):
+        with jax.named_scope("compact"):
+            return src[:, :cap], dst[:, :cap], key[:, :cap], slot[:, :cap]
+    return jax.jit(shrink)
 
 
 @functools.lru_cache(maxsize=64)
@@ -1077,23 +1105,23 @@ def _solve_bucket(
             election = "sort"
 
     with jax.enable_x64():
-        src_d = jnp.asarray(batch.src)
-        dst_d = jnp.asarray(batch.dst)
-        key_d = jnp.asarray(batch.key)
-        slot_d = jnp.asarray(batch.slot)
-        comp_dev = jnp.asarray(
-            np.broadcast_to(np.arange(n_pad, dtype=np.uint32),
-                            (B, n_pad)).copy())
-        mask_dev = jnp.zeros((B, cap), bool)
-        done_dev = jnp.zeros((B,), bool)
-        rdone_dev = jnp.zeros((B,), jnp.int32)
+        with TraceAnnotation("msf.stage"):
+            src_d = jnp.asarray(batch.src)
+            dst_d = jnp.asarray(batch.dst)
+            key_d = jnp.asarray(batch.key)
+            slot_d = jnp.asarray(batch.slot)
+            comp_dev = jnp.asarray(
+                np.broadcast_to(np.arange(n_pad, dtype=np.uint32),
+                                (B, n_pad)).copy())
+            mask_dev = jnp.zeros((B, cap), bool)
+            done_dev = jnp.zeros((B,), bool)
+            rdone_dev = jnp.zeros((B,), jnp.int32)
 
         overlap = (runtime.resolve_interval_pipeline(
             params.interval_pipeline) == 1)
         interval = max(params.batch_check_frequency, 1)
         cap_rounds = max_rounds or (n_pad + 2)
         stats = BatchStats(buckets=1, bucket_shapes=((n_pad, cap, B),))
-        history = []
         box = dict(cur_cap=cap, dispatched=0, inflight=[])
 
         fn = _build_batch_interval_fn(params.use_pallas, contract_bits,
@@ -1116,23 +1144,24 @@ def _solve_bucket(
             all_done, r, census = vals
             stats.rounds += int(r)
             stats.edges_scanned += int(r) * box["inflight"].pop(0) * B
-            history.append(int(census))
             if bool(all_done):
                 return s, True
             if params.compaction == "pow2":
                 new_cap = max(_pow2ceil(int(census)), 8)
                 if new_cap < box["cur_cap"]:   # shrink: ≤ log2 recompiles
                     comp, mask, src_d, dst_d, key_d, slot_d, done, rdone = s
-                    if contract_bits is not None:
-                        # Contraction already packed survivors to the
-                        # front — shrinking is a static slice, no readback.
-                        cfn = _build_batch_shrink_fn(new_cap)
-                        src_d, dst_d, key_d, slot_d = cfn(
-                            src_d, dst_d, key_d, slot_d)
-                    else:
-                        cfn = _build_batch_compact_fn(new_cap)
-                        src_d, dst_d, key_d, slot_d = cfn(
-                            comp, src_d, dst_d, key_d, slot_d)
+                    with TraceAnnotation("msf.compact"):
+                        if contract_bits is not None:
+                            # Contraction already packed survivors to the
+                            # front — shrinking is a static slice, no
+                            # readback.
+                            cfn = _build_batch_shrink_fn(new_cap)
+                            src_d, dst_d, key_d, slot_d = cfn(
+                                src_d, dst_d, key_d, slot_d)
+                        else:
+                            cfn = _build_batch_compact_fn(new_cap)
+                            src_d, dst_d, key_d, slot_d = cfn(
+                                comp, src_d, dst_d, key_d, slot_d)
                     s = (comp, mask, src_d, dst_d, key_d, slot_d, done,
                          rdone)
                     box["cur_cap"] = new_cap
@@ -1148,12 +1177,13 @@ def _solve_bucket(
         mask_dev, rdone_dev = state[1], state[7]
 
         # The bucket's single final fetch: mask + per-graph round counts.
-        mask_h, rdone_h = jax.device_get((mask_dev, rdone_dev))
+        with TraceAnnotation("msf.fetch"):
+            mask_h, rdone_h = jax.device_get((mask_dev, rdone_dev))
         stats.host_syncs += 1          # the bucket's final fetch
         stats.extra_syncs += 1
 
-    results = batch.unpack(mask_h)
-    stats.active_history = tuple(history)
+    with TraceAnnotation("msf.forest"):
+        results = batch.unpack(mask_h)
     stats.rounds_per_graph = tuple(int(x) for x in np.asarray(rdone_h))
     return results, stats
 
@@ -1386,7 +1416,6 @@ def _host_engine(
         [src[order], dst[order], wbits[order], eid[order]])
 
     mask = np.zeros(m, dtype=bool)
-    history = []
     cap = max_rounds or (n + 2)
     # Host mirror of the active edge set (for compaction + winner mapping).
     box = dict(active=order.copy())
@@ -1404,7 +1433,6 @@ def _host_engine(
         rnd = stats.rounds
         stats.rounds += 1
         stats.edges_scanned += int(src_d.shape[0])
-        history.append(len(box["active"]))
         if bool(done_v):
             return s, True
         stats.host_syncs += 1          # device→host: winner bitmap + ids
@@ -1442,7 +1470,6 @@ def _host_engine(
     ncomp = int(np.unique(comp_final).size)
     res = runtime.forest_from_mask(graph, mask, num_components=ncomp)
     res.check_consistent(n)
-    stats.active_history = tuple(history)
     return res, stats
 
 

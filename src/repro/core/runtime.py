@@ -46,6 +46,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import partition as partition_lib
 from repro.core.graph import PAD_VERTEX, Graph
@@ -171,30 +172,42 @@ def interval_loop(
     whose ``finish`` consumes per-interval state it would otherwise lose
     (the legacy host loops' winner bitmaps) must stay sequential.
 
+    Each dispatch, readback and ``finish`` call runs inside a profiler span
+    (``interval.dispatch``, ``interval.readback``, ``interval.finish``), so
+    a trace splits the host's time per interval between enqueueing work,
+    waiting for the device and the engine's own decisions.
+
     Raises ``RuntimeError(fail_msg)`` if ``max_intervals`` elapse without
     ``finish`` signalling done.
     """
     if not overlap:
         for _ in range(max_intervals):
-            state, scalars = dispatch(state)
-            vals = jax.device_get(scalars)  # the interval's single host sync
+            with TraceAnnotation("interval.dispatch"):
+                state, scalars = dispatch(state)
+            with TraceAnnotation("interval.readback"):
+                vals = jax.device_get(scalars)  # the interval's one host sync
             stats.host_syncs += 1
             stats.intervals += 1
-            state, done = finish(state, vals)
+            with TraceAnnotation("interval.finish"):
+                state, done = finish(state, vals)
             if done:
                 return state
         raise RuntimeError(fail_msg)
 
     # One-interval-deep pipeline: `pending` is interval k's un-consumed
     # scalar summary while `state` already holds interval k's output.
-    state, pending = dispatch(state)
+    with TraceAnnotation("interval.dispatch"):
+        state, pending = dispatch(state)
     for _ in range(max_intervals):
-        state, scalars = dispatch(state)     # interval k+1, speculative
-        vals = jax.device_get(pending)       # interval k's single host sync
+        with TraceAnnotation("interval.dispatch"):
+            state, scalars = dispatch(state)     # interval k+1, speculative
+        with TraceAnnotation("interval.readback"):
+            vals = jax.device_get(pending)       # interval k's one host sync
         stats.host_syncs += 1
         stats.intervals += 1
         stats.overlapped_syncs += 1
-        state, done = finish(state, vals)
+        with TraceAnnotation("interval.finish"):
+            state, done = finish(state, vals)
         if done:
             # Interval k terminated, so the in-flight k+1 ran on a fixed
             # point: its state is byte-identical and its scalars are never

@@ -54,6 +54,7 @@ from concurrent.futures import Future
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import incremental, mst_api, pipeline, runtime
 from repro.core.graph import Graph
@@ -105,6 +106,10 @@ class ServeStats:
     incremental-update kind (DESIGN.md §13, summed from the per-request
     :class:`~repro.core.runtime.EngineStats` ledger fields).
 
+    ``queue_wait_s`` sums, over completed requests, the seconds each
+    waited in its queue: from its ``t_submit`` to the start of the flush
+    that took it (``completed`` is its base).
+
     ``latencies_ms`` holds one submit→complete measurement per served
     request over a TRAILING window of :data:`LATENCY_WINDOW` samples (a
     bounded deque — a long soak stays memory-flat); :meth:`percentile` /
@@ -127,6 +132,7 @@ class ServeStats:
     update_requests: int = 0
     updates_applied: int = 0
     replacement_probes: int = 0
+    queue_wait_s: float = 0.0
     graphs_per_s: float = 0.0
     latencies_ms: deque = dataclasses.field(
         default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -333,11 +339,12 @@ class MSTService:
         for shape, q in list(self._queues.items()):
             while len(q) >= p.serve_lanes:
                 self.stats.size_flushes += 1
-                self._flush(shape, q, now=now if injected else None)
+                self._flush(shape, q, "size", now=now if injected else None)
                 flushed += 1
             if q and now - q[0].t_submit >= wait_s:
                 self.stats.deadline_flushes += 1
-                self._flush(shape, q, now=now if injected else None)
+                self._flush(shape, q, "deadline",
+                            now=now if injected else None)
                 flushed += 1
         return flushed
 
@@ -350,18 +357,44 @@ class MSTService:
         for shape, q in list(self._queues.items()):
             while q:
                 self.stats.drain_flushes += 1
-                self._flush(shape, q, now=now)
+                self._flush(shape, q, "drain", now=now)
                 flushed += 1
         return flushed
 
-    def _flush(self, shape: tuple, q: deque,
+    def _flush(self, shape: tuple, q: deque, trigger: str,
                now: Optional[float] = None) -> None:
+        """Take up to ``serve_lanes`` requests off ``q``, solve them and
+        complete their futures, inside a ``serve.flush`` profiler span
+        whose ``trigger`` (``size``, ``deadline`` or ``drain``) says why
+        the flush fired.  A solve flush splits into ``serve.pack``,
+        ``serve.solve`` and ``serve.complete``."""
         p = self.params
+        start = self._clock() if now is None else now
         reqs = [q.popleft() for _ in range(min(len(q), p.serve_lanes))]
+        self.stats.queue_wait_s += sum(start - r.t_submit for r in reqs)
         if shape[0] == "update":
-            results = self._solve_updates(reqs)
-        else:
-            results = self._solve_graphs(shape, reqs)
+            with TraceAnnotation("serve.flush", trigger=trigger,
+                                 occupied=len(reqs)):
+                self._complete(reqs, self._solve_updates(reqs), now)
+            return
+        n_pad, cap = shape
+        lanes = min(pow2ceil(len(reqs)), p.serve_lanes)
+        with TraceAnnotation("serve.flush", trigger=trigger, n_pad=n_pad,
+                             cap=cap, occupied=len(reqs), lanes=lanes):
+            with TraceAnnotation("serve.pack"):
+                graphs = [r.graph for r in reqs] + \
+                    [_ghost_graph() for _ in range(lanes - len(reqs))]
+                batch = pipeline.pack_bucket(graphs, n_pad, cap)
+            with TraceAnnotation("serve.solve"):
+                results, _ = mst_api.solve_packed(
+                    batch, params=self._dispatch_params(n_pad),
+                    max_rounds=self._max_rounds)
+            self.stats.ghost_lanes += lanes - len(reqs)
+            with TraceAnnotation("serve.complete"):
+                self._complete(reqs, results, now)
+
+    def _complete(self, reqs: list, results: list,
+                  now: Optional[float]) -> None:
         # Completion stamp: the injected virtual time when the dispatcher
         # was driven with one (poll(now=...) — a single timebase for the
         # whole ledger), else the real clock AFTER the solve.
@@ -388,21 +421,6 @@ class MSTService:
         return dataclasses.replace(
             p, batch_check_frequency=max(p.batch_check_frequency,
                                          n_pad + 2))
-
-    def _solve_graphs(self, shape: tuple, reqs: list) -> list:
-        """One packed bucket dispatch at the pow2-rounded occupied width."""
-        p = self.params
-        lanes = min(pow2ceil(len(reqs)), p.serve_lanes)
-        ghosts = lanes - len(reqs)
-        graphs = [r.graph for r in reqs] + \
-            [_ghost_graph() for _ in range(ghosts)]
-        n_pad, cap = shape
-        batch = pipeline.pack_bucket(graphs, n_pad, cap)
-        results, _ = mst_api.solve_packed(
-            batch, params=self._dispatch_params(n_pad),
-            max_rounds=self._max_rounds)
-        self.stats.ghost_lanes += ghosts
-        return results[:len(reqs)]
 
     def _solve_updates(self, reqs: list) -> list:
         """Plan every update's cycle/cut probe, then solve ALL candidate

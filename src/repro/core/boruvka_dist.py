@@ -3,9 +3,11 @@
 Re-formulates GHS for SPMD hardware: per round, every fragment's minimum
 outgoing edge (MOE) is ONE segmented min over packed 64-bit keys
 ``(weight_bits << 32) | edge_id`` — GHS's ``Test``/``Report`` message waves
-collapse into a single scatter-min sweep and a single fused ``pmin``
-collective (the two-phase weight + tie-break election of earlier versions is
-gone; the packed key resolves both in the same reduction).  Fragment merging
+collapse into one segmented min (two native uint32 scatter-min passes over
+the key's weight and edge-id lanes) and a single fused ``pmin`` collective
+(the two-phase weight + tie-break election of earlier versions, with a
+collective per phase, is gone; the packed key resolves both in the same
+reduction).  Fragment merging
 is min-hooking + pointer doubling (the ``Connect``/``Initiate`` waves).  The
 paper's point-to-point short-message traffic — which it identifies as its
 limiting factor (§4.2) — is off the critical path entirely.
@@ -159,7 +161,8 @@ def _one_round(
         k = jnp.where(alive, key, INF_KEY)
         # Fused MOE election: ONE segmented min over both endpoints, ONE
         # collective.  The packed key carries the tie-break, so no second
-        # (weight-match, edge-id) pass and no second pmin.
+        # pmin; locally the min runs as two uint32 scatter-min passes over
+        # the key's lanes (the chip has no 64-bit scatter).
         seg = jnp.concatenate([cs, cd]).astype(jnp.int32)
         best = segops.segment_min64(
             jnp.concatenate([k, k]), seg, num_segments=n,

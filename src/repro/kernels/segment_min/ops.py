@@ -106,9 +106,25 @@ def segment_min64(
 
     The fused MOE election calls this ONCE per round (both edge endpoints
     concatenated), so the Pallas path performs exactly one sort per round.
+
+    The XLA path is a lexicographic min in two native uint32 scatter-min
+    passes over the key's lanes: the weight lane first, then the edge-id
+    lane of the keys that tie each segment's minimum weight.  The chip has
+    no 64-bit integer unit, and a uint64 scatter-min lowers to an emulated
+    pair-of-words combiner an order of magnitude slower per update.
     """
     if not use_pallas:
-        return ref.segment_min64(key, seg, num_segments)
+        if num_segments == 0:
+            return jnp.full((0,), INF_U64, jnp.uint64)
+        hi, lo = keys_lib.split_key_lanes(key)
+        best_hi = jnp.full((num_segments,), INF_U32, jnp.uint32).at[
+            seg].min(hi, mode="drop")
+        # Only the ties of each segment's minimum weight land; the rest,
+        # and out-of-range segments, go to slot num_segments and drop.
+        tie = jnp.where(hi == best_hi[seg], seg, num_segments)
+        best_lo = jnp.full((num_segments,), INF_U32, jnp.uint32).at[
+            tie].min(lo, mode="drop")
+        return keys_lib.combine_key_lanes(best_hi, best_lo)
     if order is None:
         order = jnp.argsort(seg)
     return segment_min64_sorted(

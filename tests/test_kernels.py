@@ -58,6 +58,76 @@ def test_segment_min64_packed_key_sweep(m, s):
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
+def _keys(hi, lo):
+    return ((np.asarray(hi, np.uint64) << np.uint64(32))
+            | np.asarray(lo, np.uint64))
+
+
+def _case_hi_ties(rng):
+    """Many equal weight lanes per segment: the edge-id pass decides."""
+    m, s = 3000, 7
+    return (_keys(rng.integers(0, 4, m), rng.integers(0, 2**32 - 1, m)),
+            rng.integers(0, s, m), s)
+
+
+def _case_inf_and_empty(rng):
+    """Segments 0-9 hold only INF_KEY, 10-14 live keys, 15-19 nothing."""
+    key = np.concatenate([np.full(200, 0xFFFFFFFFFFFFFFFF, np.uint64),
+                          _keys(rng.integers(0, 2**31, 300),
+                                rng.integers(0, 2**32 - 1, 300))])
+    seg = np.concatenate([rng.integers(0, 10, 200),
+                          rng.integers(10, 15, 300)])
+    return key, seg, 20
+
+
+def _case_out_of_range(rng):
+    """Segments past either end drop, even with the smallest keys."""
+    m, s = 1000, 16
+    key = _keys(rng.integers(5, 2**31, m), rng.integers(0, 2**32 - 1, m))
+    seg = rng.integers(0, s, m)
+    bad = rng.choice(m, 200, replace=False)
+    seg[bad] = rng.choice([s, s + 5, 0x7FFF0000, -s - 3], 200)
+    key[bad] = _keys(rng.integers(0, 5, 200), rng.integers(0, 2**32 - 1, 200))
+    return key, seg, s
+
+
+def _case_hub(rng):
+    """R-MAT-like skew: one segment holds half of the updates."""
+    m, s = 4096, 64
+    seg = rng.integers(0, s, m)
+    seg[rng.random(m) < 0.5] = 3
+    return (_keys(rng.integers(0, 2**12, m), rng.integers(0, 2**32 - 1, m)),
+            seg, s)
+
+
+def _case_no_updates(rng):
+    return np.zeros(0, np.uint64), np.zeros(0, np.int64), 5
+
+
+def _case_no_segments(rng):
+    return _keys(rng.integers(0, 9, 50), rng.integers(0, 9, 50)), \
+        rng.integers(0, 3, 50), 0
+
+
+@pytest.mark.parametrize("case", [_case_hi_ties, _case_inf_and_empty,
+                                  _case_out_of_range, _case_hub,
+                                  _case_no_updates, _case_no_segments],
+                         ids=["hi_ties", "inf_and_empty", "out_of_range",
+                              "hub", "m0", "s0"])
+def test_segment_min64_lane_passes_match_u64_scatter(case):
+    """The XLA path (two uint32 scatter-min passes over the key's lanes)
+    equals the uint64 scatter-min oracle bit for bit."""
+    from repro.kernels.segment_min import ops, ref
+    key, seg, s = case(np.random.default_rng(7))
+    with jax.enable_x64():
+        k = jnp.asarray(key, jnp.uint64)
+        g = jnp.asarray(seg, jnp.int32)
+        got = ops.segment_min64(k, g, num_segments=s, use_pallas=False)
+        want = ref.segment_min64(k, g, s)
+        assert got.dtype == jnp.uint64
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_segmented_min2_scan_matches_oracle():
     from repro.kernels.segment_min import ref
     from repro.kernels.segment_min.segment_min import segmented_min2_scan

@@ -98,6 +98,28 @@ def test_segmented_min_is_scoped_as_election():
     assert names and all("/election/" in n for n in names)
 
 
+def test_single_interval_scatters_no_u64():
+    """The election's segmented min runs as native uint32 scatter-min
+    passes under the ``election`` scope: no scatter of the single-graph
+    interval writes 64-bit words, which the chip only emulates."""
+    with jax.enable_x64():
+        text = _lower_single().compile().as_text()
+    scatters = [line for line in text.splitlines()
+                if re.search(r"=\s*[^=]*?\bscatter\(", line)]
+    assert scatters
+    wide = [line.strip()[:160] for line in scatters
+            if re.search(r"=\s*\(?[us]64\[", line)]
+    assert not wide, wide
+    elect = [line for line in scatters if "segment_min64" in line]
+    assert len(elect) == 2 and all(
+        re.search(r"=\s*u32\[", line) and "/election/" in line
+        for line in elect), elect
+    gathers = [line for line in text.splitlines()
+               if "segment_min64" in line
+               and re.search(r"=\s*[^=]*?\bgather\(", line)]
+    assert gathers and all("/election/" in line for line in gathers)
+
+
 # -- host spans ---------------------------------------------------------------
 
 def _traced(tmp_path, run):

@@ -42,6 +42,10 @@ Generator kinds (§4 shapes + new scenarios):
   dominated by squared Euclidean distance (approximate-kNN structure).
 * ``grid``    — road-like: 4-neighbor lattice links with light weights
   plus a sparse set of heavy long-range shortcuts.
+* ``grid2d``  — GRID2D of Sanders & Schimek, *Engineering Massively
+  Parallel MST Algorithms* (IPDPS 2023, arXiv:2302.12199): a 2-D lattice,
+  each vertex joined to its 4 neighbours, uniform random weights, no
+  shortcuts and no torus.
 * ``chain``   — adversarial path (maximum Borůvka round count / fragment
   depth); half the samples are duplicate path edges to stress dedup.
 * ``star``    — adversarial hub (every edge incident to vertex 0; each
@@ -59,11 +63,12 @@ import numpy as np
 from repro.core import keys as keys_lib
 from repro.core.graph import Graph, PAD_VERTEX, preprocess
 
-KINDS = ("rmat", "random", "geo_knn", "grid", "chain", "star")
+KINDS = ("rmat", "random", "geo_knn", "grid", "grid2d", "chain", "star")
 
 # R-MAT quadrant thresholds (a=0.57, b=0.19, c=0.19 — Graph500).
 _RMAT_T = (np.float32(0.57), np.float32(0.76), np.float32(0.95))
 _GEO_WINDOW = 2                     # 5×5 neighbor window
+_GRID2D_WEIGHT_STREAM = 99          # used by no other sampler
 _MASK64 = (1 << 64) - 1
 
 
@@ -97,6 +102,8 @@ class GraphSpec:
             return n * self.avg_degree // 2
         if self.kind == "grid":
             return 2 * n + max(n // 16, 1)      # lattice links + shortcuts
+        if self.kind == "grid2d":
+            return 2 * n                        # right links, down links
         return 2 * max(n - 1, 1)                # chain / star: spokes twice
 
 
@@ -185,27 +192,40 @@ def _sample_geo_knn(xp, spec: GraphSpec, ctr):
     return u, v, _to_f32_unit(wbits.astype(np.uint32))
 
 
-def _sample_grid(xp, spec: GraphSpec, ctr):
-    n, seed = spec.num_vertices, spec.seed
+def _lattice_link(xp, spec: GraphSpec, ctr):
+    """Counter ``c < n`` is vertex ``c``'s right link on a lattice of
+    ``2**(scale // 2)`` columns, ``n ≤ c < 2n`` the down link of vertex
+    ``c - n``.  Border links clamp to self-loops (dropped): no torus."""
+    n = spec.num_vertices
     side = 1 << (spec.scale // 2)
     rows = n // side
-    is_right = ctr < np.uint64(n)
-    is_down = (ctr >= np.uint64(n)) & (ctr < np.uint64(2 * n))
-    lattice = is_right | is_down
     v = ctr & np.uint64(n - 1)
     vx = v % np.uint64(side)
     vy = v // np.uint64(side)
-    # Border links clamp to self-loops (dropped): a road grid, not a torus.
     right = xp.where(vx < np.uint64(side - 1), v + np.uint64(1), v)
     down = xp.where(vy < np.uint64(rows - 1), v + np.uint64(side), v)
+    return v, xp.where(ctr < np.uint64(n), right, down)
+
+
+def _sample_grid(xp, spec: GraphSpec, ctr):
+    n, seed = spec.num_vertices, spec.seed
+    lattice = ctr < np.uint64(2 * n)
+    v, link = _lattice_link(xp, spec, ctr)
     su = _rand_u64(seed, 10, ctr) & np.uint64(n - 1)
     sv = _rand_u64(seed, 11, ctr) & np.uint64(n - 1)
     src = xp.where(lattice, v, su)
-    dst = xp.where(is_right, right, xp.where(is_down, down, sv))
+    dst = xp.where(lattice, link, sv)
     # Lattice roads are light (< 0.5); shortcuts are heavy (≥ 0.5) highways.
     bits22 = _rand_u64(seed, 12, ctr) & np.uint64((1 << 22) - 1)
     wbits = xp.where(lattice, bits22, bits22 | np.uint64(1 << 22))
     return src, dst, _to_f32_unit(wbits.astype(np.uint32))
+
+
+def _sample_grid2d(xp, spec: GraphSpec, ctr):
+    """The lattice's links alone; weights are uniform 23-bit, so ties
+    occur."""
+    v, link = _lattice_link(xp, spec, ctr)
+    return v, link, _unif01(spec.seed, _GRID2D_WEIGHT_STREAM, ctr)
 
 
 def _sample_chain(xp, spec: GraphSpec, ctr):
@@ -227,6 +247,7 @@ _SAMPLERS = {
     "random": _sample_random,
     "geo_knn": _sample_geo_knn,
     "grid": _sample_grid,
+    "grid2d": _sample_grid2d,
     "chain": _sample_chain,
     "star": _sample_star,
 }

@@ -4,9 +4,16 @@ This is the synchronous stand-in for GHS fragment merging: min-hooking builds
 a strictly-decreasing parent forest (no cycles by construction), and pointer
 doubling compresses it in ⌈log2 N⌉ steps — the O(log) collapse of the GHS
 ``Initiate`` broadcast described in DESIGN.md §3.
+
+:func:`pointer_double` is a ``jax.jit`` of its own, as ``segment_min64``
+is, so that a device trace names its gathers ``pointer_double/gather``.
+Called from inside another jitted program it is inlined: the compiled
+program and its results are those of the plain function, and its ops keep
+the caller's ``named_scope``.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -29,6 +36,7 @@ def hook_min(
                                  mode="drop")
 
 
+@functools.partial(jax.jit, static_argnames=("num_steps",))
 def pointer_double(parent: jnp.ndarray, num_steps: int | None = None) -> jnp.ndarray:
     """Full path compression by pointer doubling (⌈log2 N⌉ gathers)."""
     n = parent.shape[0]

@@ -286,6 +286,32 @@ def test_device_pipeline_determinism(kind):
                          pipeline.build(spec).to_graph())
 
 
+@pytest.mark.parametrize("scale", [7, 8, 9])
+def test_grid2d_is_the_4_neighbour_lattice(scale):
+    """GRID2D: every canonical edge joins lattice neighbours, no vertex has
+    more than 4, the count is the lattice's, the weights are 23-bit draws
+    that tie, and the device build's forest is Kruskal's."""
+    spec = GraphSpec("grid2d", scale, seed=11)
+    side = 1 << (scale // 2)
+    rows = spec.num_vertices // side
+    g = pipeline.build_host(spec)
+    assert g.num_edges == rows * (side - 1) + (rows - 1) * side
+    deg = np.bincount(np.concatenate([g.src, g.dst]),
+                      minlength=spec.num_vertices)
+    assert deg.max() == 4 and deg.min() == 2
+    step = g.dst.astype(np.int64) - g.src.astype(np.int64)
+    right = (step == 1) & (g.src % side != side - 1)
+    assert (right | (step == side)).all()
+    bits = g.weight.astype(np.float64) * 2.0 ** 23 - 0.5
+    assert (bits == np.floor(bits)).all() and (bits < 2 ** 23).all()
+    wide = pipeline.build_host(GraphSpec("grid2d", scale + 6, seed=11))
+    assert np.unique(wide.weight).size < wide.num_edges
+    want = kruskal_ref.kruskal(g)
+    got, _ = minimum_spanning_forest(pipeline.build(spec))
+    assert np.array_equal(got.edge_mask, want.edge_mask)
+    assert int(got.edge_mask.sum()) == spec.num_vertices - 1
+
+
 def test_packed_keys_cached_across_pads():
     g = generators.generate("random", 7, seed=5)
     first = g.packed_keys

@@ -98,6 +98,17 @@ def test_segmented_min_is_scoped_as_election():
     assert names and all("/election/" in n for n in names)
 
 
+def test_pointer_doubling_is_named_under_merge():
+    """``union_find.pointer_double`` is a jit of its own, inlined under
+    the caller's ``merge`` scope: a device trace names its gathers
+    ``pointer_double/gather``."""
+    with jax.enable_x64():
+        text = _lower_single().compile().as_text()
+    names = re.findall(r'op_name="([^"]*jit\(pointer_double\)[^"]*)"', text)
+    assert any(n.endswith("/gather") for n in names)
+    assert all("/merge/jit(pointer_double)" in n for n in names)
+
+
 def test_single_interval_scatters_no_u64():
     """The election's segmented min runs as native uint32 scatter-min
     passes under the ``election`` scope: no scatter of the single-graph

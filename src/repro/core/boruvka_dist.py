@@ -115,6 +115,9 @@ class BoruvkaStats(runtime.EngineStats):
     rounds: int = 0
     compactions: int = 0
     edges_scanned: int = 0          # Σ active (padded) edges per round
+    doubling_steps: int = 0         # Σ pointer-doubling steps run per
+                                    # round (a bucket counts its slowest
+                                    # lane: the steps the vmapped loop ran)
     comm_history: tuple = ()        # device loop: one (mode, cand_cap,
                                     # rounds, bytes) record per consumed
                                     # interval — per-shard on-wire bytes of
@@ -149,7 +152,8 @@ def _one_round(
     The phases run under the ``jax.named_scope`` names ``election``,
     ``record`` and ``merge``, which a profile's device ops carry in their
     op name (the interval's censuses and compaction add ``census`` and
-    ``compact``).
+    ``compact``).  Returns the new state, the done flag and the number of
+    pointer-doubling steps the merge ran.
     """
     from repro.kernels.segment_min import ops as segops
     n = comp.shape[0]
@@ -181,9 +185,9 @@ def _one_round(
         lo = jnp.minimum(cs, cd).astype(jnp.uint32)
         parent = union_find.hook_min(n, hi, lo, winners)
         parent = pmin(parent, jnp.arange(n, dtype=jnp.uint32))
-        parent = union_find.pointer_double(parent)
+        parent, steps = union_find.pointer_double(parent)
         comp = parent[comp]
-    return comp, mask, done
+    return comp, mask, done, steps
 
 
 def _censuses(comp, src, dst, key, axis_name: Optional[str],
@@ -242,8 +246,9 @@ def _run_interval(
     the (possibly compacted) local edge arrays.  Each edge carries its own
     load-time ``slot`` index, so winner recording is a local scatter under
     ANY partition and survives compaction.  Returns the new state plus a
-    replicated (done, rounds-run, max local active count, max local
-    candidate count) vector — the ONLY values the host ever reads.
+    replicated (done, rounds-run, doubling steps, max local active count,
+    max local candidate count) vector — the ONLY values the host ever
+    reads.
 
     ``collective``/``cand_cap`` pick the cross-shard reduction (DESIGN.md
     §11): full-width ``lax.pmin`` or the compressed delta exchange with a
@@ -257,17 +262,18 @@ def _run_interval(
                           pmin=pmin, use_pallas=use_pallas)
 
     def cond(c):
-        r, _, _, done = c
+        r, _, _, done, _ = c
         return jnp.logical_not(done) & (r < rounds)
 
     def body(c):
-        r, comp, mask, _ = c
-        comp, mask, done = one_round(comp, mask)
-        return r + 1, comp, mask, done
+        r, comp, mask, _, dbl = c
+        comp, mask, done, steps = one_round(comp, mask)
+        return r + 1, comp, mask, done, dbl + steps
 
-    r, comp, mask, done = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), comp, mask, jnp.bool_(False)))
-    return (comp, mask, done, r,
+    r, comp, mask, done, dbl = jax.lax.while_loop(
+        cond, body,
+        (jnp.int32(0), comp, mask, jnp.bool_(False), jnp.int32(0)))
+    return (comp, mask, done, r, dbl,
             *_censuses(comp, src, dst, key, axis_name, collective))
 
 
@@ -302,7 +308,8 @@ def _one_round_fused(
     * ``best`` is already globally reduced, so the hook requests are
       identical on every shard and :func:`_one_round`'s second collective
       (the parent ``pmin``) is dropped — ONE collective per round;
-    * shortcut + relabel fuse into ``spmv_minplus.shortcut_relabel``.
+    * shortcut + relabel are :func:`_one_round`'s: pointer doubling to
+      its fixpoint, then ``parent[comp]``.
 
     Election over identical packed keys, identical winner set, identical
     hook pairs (each elected fragment contributes the same (hi, lo) its
@@ -335,8 +342,9 @@ def _one_round_fused(
         hi = jnp.maximum(f, other)
         lo = jnp.minimum(f, other)
         parent = union_find.hook_min(n, hi, lo, elected)
-        comp = spmv_ops.shortcut_relabel(parent, comp)
-    return comp, mask, done
+        parent, steps = union_find.pointer_double(parent)
+        comp = parent[comp]
+    return comp, mask, done, steps
 
 
 def _run_interval_fused(
@@ -375,17 +383,18 @@ def _run_interval_fused(
                                 sort_bits=sort_bits)
 
     def cond(c):
-        r, _, _, done = c
+        r, _, _, done, _ = c
         return jnp.logical_not(done) & (r < rounds)
 
     def body(c):
-        r, comp, mask, _ = c
-        comp, mask, done = one_round(comp, mask)
-        return r + 1, comp, mask, done
+        r, comp, mask, _, dbl = c
+        comp, mask, done, steps = one_round(comp, mask)
+        return r + 1, comp, mask, done, dbl + steps
 
-    r, comp, mask, done = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), comp, mask, jnp.bool_(False)))
-    return (comp, mask, done, r,
+    r, comp, mask, done, dbl = jax.lax.while_loop(
+        cond, body,
+        (jnp.int32(0), comp, mask, jnp.bool_(False), jnp.int32(0)))
+    return (comp, mask, done, r, dbl,
             *_censuses(comp, src, dst, key, axis_name, collective))
 
 
@@ -411,7 +420,7 @@ def _build_interval_fn_fused(
         # mask + canonical endpoints replicated (see _run_interval_fused);
         # only the edge working set is sharded.
         in_specs=(P(), P(), P(_AXIS), P(_AXIS), P(_AXIS), P(), P(), P()),
-        out_specs=(P(), P(), P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P(), P(), P()),
     )
     return jax.jit(fn, donate_argnums=donate)
 
@@ -463,7 +472,7 @@ def _build_interval_fn(mesh: Optional[Mesh], use_pallas: bool,
         mesh,
         in_specs=(P(), P(_AXIS), P(_AXIS), P(_AXIS), P(_AXIS), P(_AXIS),
                   P()),
-        out_specs=(P(), P(_AXIS), P(), P(), P(), P()),
+        out_specs=(P(), P(_AXIS), P(), P(), P(), P(), P()),
     )
     return jax.jit(fn, donate_argnums=donate)
 
@@ -601,11 +610,11 @@ def _device_engine(
                               0)
             f, mode, cand_cap, bytes_per_round = pick_fn()
             if fused:
-                comp_dev, mask_dev, done_t, r_t, act_t, cand_t = f(
+                comp_dev, mask_dev, *summary = f(
                     comp_dev, mask_dev, src_d, dst_d, key_d, csrc_d, cdst_d,
                     this_rounds)
             else:
-                comp_dev, mask_dev, done_t, r_t, act_t, cand_t = f(
+                comp_dev, mask_dev, *summary = f(
                     comp_dev, mask_dev, src_d, dst_d, key_d, slot_d,
                     this_rounds)
             box["dispatched"] += this_rounds
@@ -614,16 +623,18 @@ def _device_engine(
             # the rounds the interval actually ran.
             box["inflight"].append(
                 (mode, cand_cap, box["cur_block"], bytes_per_round))
-            # The interval's scalar summary: four replicated values,
-            # fetched by the runtime with ONE device_get.
+            # The interval's scalar summary: five replicated values
+            # (done, rounds, doubling steps, censuses), fetched by the
+            # runtime with ONE device_get.
             return (comp_dev, mask_dev, src_d, dst_d, key_d, slot_d), \
-                (done_t, r_t, act_t, cand_t)
+                tuple(summary)
 
         def finish(s, vals):
-            done_v, r, n_act, n_cand = vals
+            done_v, r, dbl, n_act, n_cand = vals
             mode, cand_cap, blk, bytes_per_round = box["inflight"].pop(0)
             r = int(r)
             stats.rounds += r
+            stats.doubling_steps += int(dbl)
             stats.edges_scanned += r * blk * num_shards
             stats.comm_bytes += r * bytes_per_round
             comm_hist.append((mode, cand_cap, r, r * bytes_per_round))
@@ -697,6 +708,7 @@ class BatchStats(BoruvkaStats):
         self.rounds += st.rounds
         self.compactions += st.compactions
         self.edges_scanned += st.edges_scanned
+        self.doubling_steps += st.doubling_steps
         self.overlapped_syncs += st.overlapped_syncs
         self.speculative_intervals += st.speculative_intervals
         self.comm_bytes += st.comm_bytes
@@ -773,9 +785,9 @@ def _one_round_packed(comp, mask, src, dst, key, slot, *,
         hi = jnp.maximum(f, other)
         lo = jnp.minimum(f, other)
         parent = union_find.hook_min(n, hi, lo, elected)
-        parent = union_find.pointer_double(parent)
+        parent, steps = union_find.pointer_double(parent)
         comp = parent[comp]
-    return comp, mask, done
+    return comp, mask, done, steps
 
 
 def _contract_lane(comp, src, dst, key, *, s_bits: int, c_bits: int):
@@ -872,21 +884,22 @@ def _run_interval_batch(
                                 use_pallas=use_pallas))
 
     def cond(c):
-        r, _, _, done, _ = c
+        r, _, _, done, _, _ = c
         return jnp.logical_not(jnp.all(done)) & (r < rounds)
 
     def body(c):
-        r, comp, mask, done, rdone = c
-        comp2, mask2, done2 = step(comp, mask, src, dst, key, slot)
+        r, comp, mask, done, rdone, dbl = c
+        comp2, mask2, done2, steps = step(comp, mask, src, dst, key, slot)
         live = jnp.logical_not(done)
         comp = jnp.where(live[:, None], comp2, comp)
         mask = jnp.where(live[:, None], mask2, mask)
         rdone = rdone + live.astype(jnp.int32)
         done = done | done2
-        return r + 1, comp, mask, done, rdone
+        # The vmapped doubling ran until its slowest lane stopped.
+        return r + 1, comp, mask, done, rdone, dbl + steps.max()
 
-    r, comp, mask, done, rdone = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), comp, mask, done, rdone))
+    r, comp, mask, done, rdone, dbl = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), comp, mask, done, rdone, jnp.int32(0)))
 
     if contract_bits is not None:
         s_bits, c_bits = contract_bits
@@ -901,7 +914,7 @@ def _run_interval_batch(
                 lambda c, s, d, k: ((c[s] != c[d]) & (k != INF_KEY)).sum(
                     dtype=jnp.int32))(comp, src, dst, key).max()
     return (comp, mask, src, dst, key, slot, done, rdone,
-            jnp.all(done), r, census)
+            jnp.all(done), r, dbl, census)
 
 
 @functools.lru_cache(maxsize=256)
@@ -1144,8 +1157,9 @@ def _solve_bucket(
             return state[:8], state[8:]
 
         def finish(s, vals):
-            all_done, r, census = vals
+            all_done, r, dbl, census = vals
             stats.rounds += int(r)
+            stats.doubling_steps += int(dbl)
             stats.edges_scanned += int(r) * box["inflight"].pop(0) * B
             if bool(all_done):
                 return s, True
@@ -1342,11 +1356,11 @@ def _round_body(
     lo = jnp.minimum(cs, cd).astype(jnp.uint32)
     parent = union_find.hook_min(n, hi, lo, winners)
     parent = pmin(parent)
-    parent = union_find.pointer_double(parent)
+    parent, steps = union_find.pointer_double(parent)
     new_comp = parent[comp]
 
     done = jnp.all(bw == INF32)
-    return new_comp, winners, done
+    return new_comp, winners, done, steps
 
 
 def _make_round_fn(mesh: Optional[Mesh], use_pallas: bool = False) -> Callable:
@@ -1357,7 +1371,7 @@ def _make_round_fn(mesh: Optional[Mesh], use_pallas: bool = False) -> Callable:
         partial(_round_body, axis_name=_AXIS, use_pallas=use_pallas),
         mesh,
         in_specs=(P(), P(_AXIS), P(_AXIS), P(_AXIS), P(_AXIS)),
-        out_specs=(P(), P(_AXIS), P()),
+        out_specs=(P(), P(_AXIS), P(), P()),
     )
     return jax.jit(fn)
 
@@ -1425,16 +1439,19 @@ def _host_engine(
 
     def dispatch(s):
         comp_dev, src_d, dst_d, wb_d, eid_d, _ = s
-        comp_dev, winners, done = round_fn(comp_dev, src_d, dst_d, wb_d,
-                                           eid_d)
-        # The runtime fetches the done flag (the legacy loop's per-round
-        # sync); the winner bitmap readback below is an extra, metered one.
-        return (comp_dev, src_d, dst_d, wb_d, eid_d, winners), done
+        comp_dev, winners, done, steps = round_fn(comp_dev, src_d, dst_d,
+                                                  wb_d, eid_d)
+        # The runtime fetches the done flag and the doubling steps (the
+        # legacy loop's per-round sync); the winner bitmap readback below
+        # is an extra, metered one.
+        return (comp_dev, src_d, dst_d, wb_d, eid_d, winners), (done, steps)
 
-    def finish(s, done_v):
+    def finish(s, vals):
+        done_v, steps = vals
         comp_dev, src_d, dst_d, wb_d, eid_d, winners = s
         rnd = stats.rounds
         stats.rounds += 1
+        stats.doubling_steps += int(steps)
         stats.edges_scanned += int(src_d.shape[0])
         if bool(done_v):
             return s, True

@@ -2,8 +2,12 @@
 
 This is the synchronous stand-in for GHS fragment merging: min-hooking builds
 a strictly-decreasing parent forest (no cycles by construction), and pointer
-doubling compresses it in ⌈log2 N⌉ steps — the O(log) collapse of the GHS
-``Initiate`` broadcast described in DESIGN.md §3.
+doubling compresses it — the O(log) collapse of the GHS ``Initiate``
+broadcast described in DESIGN.md §3.  Doubling stops at its fixpoint: a
+forest of depth d is flat after ⌈log2 d⌉ steps, and one more step, whose
+gather changes nothing, confirms it.  The step count is capped at
+⌈log2 N⌉, the most any forest over N vertices needs, so a chain of N
+vertices runs exactly the cap.
 
 :func:`pointer_double` is a ``jax.jit`` of its own, as ``segment_min64``
 is, so that a device trace names its gathers ``pointer_double/gather``.
@@ -37,13 +41,33 @@ def hook_min(
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps",))
-def pointer_double(parent: jnp.ndarray, num_steps: int | None = None) -> jnp.ndarray:
-    """Full path compression by pointer doubling (⌈log2 N⌉ gathers)."""
+def pointer_double(
+    parent: jnp.ndarray, num_steps: int | None = None
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Path compression by pointer doubling, to the fixpoint.
+
+    Each step is one gather, ``q = p[p]``; the loop carries ``q`` and
+    whether any entry moved, and stops once nothing did or after
+    ``num_steps`` steps (default ⌈log2 N⌉).  Returns the compressed parent
+    and the number of steps run (int32): ⌈log2 depth⌉ + 1 below the cap.
+    The result equals ``num_steps`` unconditional steps, since doubling a
+    flat forest changes nothing.  Under ``vmap`` the loop runs until every
+    lane has stopped, and each lane reports its own count; under
+    ``shard_map`` a replicated parent gives every shard the same exit.
+    """
     n = parent.shape[0]
     if num_steps is None:
         num_steps = max(1, math.ceil(math.log2(max(n, 2))))
 
-    def body(_, p):
-        return p[p]
+    def cond(c):
+        i, _, moved = c
+        return moved & (i < num_steps)
 
-    return jax.lax.fori_loop(0, num_steps, body, parent)
+    def body(c):
+        i, p, _ = c
+        q = p[p]
+        return i + 1, q, jnp.any(q != p)
+
+    steps, parent, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), parent, jnp.bool_(True)))
+    return parent, steps

@@ -274,9 +274,9 @@ def component_maxkey(
 
 @jax.jit
 def shortcut_relabel(parent: jnp.ndarray, comp: jnp.ndarray) -> jnp.ndarray:
-    """Pointer-jumping shortcut + fragment relabel,
-    ``union_find.pointer_double(parent)[comp]``, on every lowering: the
-    doubling steps are data-dependent gathers over all ``n`` labels, which
-    stay in XLA (no Pallas kernel — see the :mod:`.spmv_minplus`
-    docstring)."""
+    """Pointer-jumping shortcut + fragment relabel: the parent of
+    ``union_find.pointer_double(parent)``, indexed by ``comp``, on every
+    lowering: the doubling steps are data-dependent gathers over all ``n``
+    labels, which stay in XLA (no Pallas kernel — see the
+    :mod:`.spmv_minplus` docstring)."""
     return ref.shortcut_relabel(parent, comp)

@@ -28,4 +28,4 @@ def elect(cs: jnp.ndarray, cd: jnp.ndarray, key: jnp.ndarray,
 
 def shortcut_relabel(parent: jnp.ndarray, comp: jnp.ndarray) -> jnp.ndarray:
     """Oracle for the fused shortcut: full pointer doubling, then relabel."""
-    return union_find.pointer_double(parent)[comp]
+    return union_find.pointer_double(parent)[0][comp]

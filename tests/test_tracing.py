@@ -107,6 +107,12 @@ def test_pointer_doubling_is_named_under_merge():
     names = re.findall(r'op_name="([^"]*jit\(pointer_double\)[^"]*)"', text)
     assert any(n.endswith("/gather") for n in names)
     assert all("/merge/jit(pointer_double)" in n for n in names)
+    # The doubling loop's one gather a step sits in the while body, and
+    # keeps the name that ``shortcut_share`` reads.
+    body = [n for n in names if n.endswith("/while/body/gather")]
+    assert body and all(
+        n.endswith("/merge/jit(pointer_double)/while/body/gather")
+        for n in body)
 
 
 def test_single_interval_scatters_no_u64():
